@@ -15,12 +15,11 @@ ones:
     threshold and stay legal.
 ``donation_audit``
     compares donations *requested* against donations *honored*: an
-    honored donation appears as ``tf.aliasing_output``/``jax.buffer_donor``
-    on the lowered MLIR parameter and as an ``input_output_alias`` entry
-    in the compiled HLO module; a donated-but-copied arg (dtype changed,
-    shape changed, output mismatch) appears in neither, and XLA quietly
-    keeps both buffers — the engine's donate-through-scan memory story
-    depends on these actually aliasing.
+    honored donation is an ``input_output_alias`` entry in the compiled
+    HLO module; a donated-but-copied arg (dtype changed, shape changed,
+    output mismatch) has none, and XLA quietly keeps both buffers — the
+    engine's donate-through-scan memory story depends on these actually
+    aliasing.
 
 ``utils.hlo`` re-exports the migrated names with a DeprecationWarning.
 """
@@ -30,6 +29,7 @@ import re
 from dataclasses import dataclass, field
 
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr, Var
 
 # dtype -> bytes per element (HLO + StableHLO spellings)
 _DTYPE_BYTES = {
@@ -132,7 +132,6 @@ def duplicate_fusion_count(hlo_text: str) -> int:
 # jaxpr liveness analysis (memory-bound claims)
 # ---------------------------------------------------------------------
 def _sub_jaxprs(val):
-    from jax.core import ClosedJaxpr, Jaxpr
     if isinstance(val, ClosedJaxpr):
         yield val.jaxpr
     elif isinstance(val, Jaxpr):
@@ -151,7 +150,6 @@ def _live_walk(jaxpr, visit) -> None:
     skipped, so visited equations reflect what a compiled program
     actually executes.
     """
-    from jax.core import Var
     live = {v for v in jaxpr.outvars if isinstance(v, Var)}
     for eqn in reversed(jaxpr.eqns):
         if not any(isinstance(v, Var) and v in live for v in eqn.outvars):
@@ -259,24 +257,24 @@ def dtype_drift(jaxpr, src="bfloat16", dst="float32",
 # ---------------------------------------------------------------------
 # donation audit (donated args XLA copied anyway)
 # ---------------------------------------------------------------------
-_DONOR_RE = re.compile(r"tf\.aliasing_output|jax\.buffer_donor")
-_ALIAS_RE = re.compile(r"input_output_alias=\{([^}]*(?:\{[^}]*\}[^}]*)*)\}")
+_ALIAS_RE = re.compile(r"input_output_alias=\{((?:[^{}]|\{[^{}]*\})*)\}")
 _ALIAS_ENTRY_RE = re.compile(r"\{[0-9, ]*\}:")
 
 
 @dataclass(frozen=True)
 class DonationReport:
-    """Requested vs honored donations for one lowered/compiled program.
+    """Requested vs honored donations for one compiled program.
 
-    ``requested`` counts flat donated inputs (from ``donate_argnums``),
-    ``honored`` counts lowered parameters carrying a donor/aliasing
-    attribute, ``aliased`` counts compiled input_output_alias entries
-    (-1 when no compiled module was supplied).  ``requested > honored``
-    means XLA copies a buffer the caller believes it reuses in place.
+    ``requested`` counts flat donated inputs (from ``donate_argnums``);
+    ``honored`` counts the compiled module's ``input_output_alias``
+    entries, i.e. donated buffers the executable really reuses for an
+    output.  The lowered MLIR is no evidence: it marks every donated
+    parameter (``jax.buffer_donor``) whether or not XLA can alias it.
+    ``requested > honored`` means XLA copies a buffer the caller believes
+    it reuses in place.
     """
     requested: int
     honored: int
-    aliased: int
 
     @property
     def copied(self) -> int:
@@ -297,21 +295,8 @@ def donation_audit(fn_or_lowered, *args, **kwargs) -> DonationReport:
     lowered = fn_or_lowered
     if not hasattr(lowered, "as_text"):
         lowered = fn_or_lowered.lower(*args, **kwargs)
-    mlir = lowered.as_text()
-    honored = len(_DONOR_RE.findall(mlir))
-    # flat donated-input count straight from the lowering metadata
-    requested = honored
-    try:
-        flat, _ = jax.tree.flatten(lowered.args_info)
-        requested = sum(bool(getattr(a, "donated", False)) for a in flat)
-    except Exception:
-        pass
-    aliased = -1
-    try:
-        hlo = lowered.compile().as_text()
-        m = _ALIAS_RE.search(hlo)
-        aliased = len(_ALIAS_ENTRY_RE.findall(m.group(1))) if m else 0
-    except Exception:
-        pass
-    return DonationReport(requested=requested, honored=honored,
-                          aliased=aliased)
+    flat, _ = jax.tree.flatten(lowered.args_info)
+    requested = sum(bool(a.donated) for a in flat)
+    m = _ALIAS_RE.search(lowered.compile().as_text())
+    honored = len(_ALIAS_ENTRY_RE.findall(m.group(1))) if m else 0
+    return DonationReport(requested=requested, honored=honored)

@@ -36,8 +36,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.grouping import group_major_order
 from repro.optim.optimizers import Optimizer, apply_updates
@@ -327,10 +326,10 @@ class VectorizedClientEngine:
             vf = jax.vmap(self._one_client())
             if self._use_shard_map():
                 spec = P(CLIENT_AXIS)
-                vf = shard_map(vf, mesh=self.mesh,
-                               in_specs=(spec,) * 5,
-                               out_specs=(spec, spec, spec),
-                               check_rep=False)
+                vf = jax.shard_map(vf, mesh=self.mesh,
+                                   in_specs=(spec,) * 5,
+                                   out_specs=(spec, spec, spec),
+                                   check_vma=False)
             self._vec_fn = jax.jit(vf)
         return self._vec_fn
 
@@ -340,10 +339,10 @@ class VectorizedClientEngine:
                           in_axes=(0, 0, 0, 0, 0, None))
             if self._use_shard_map():
                 spec = P(CLIENT_AXIS)
-                vf = shard_map(vf, mesh=self.mesh,
-                               in_specs=(spec,) * 5 + (P(),),
-                               out_specs=(spec, spec, spec),
-                               check_rep=False)
+                vf = jax.shard_map(vf, mesh=self.mesh,
+                                   in_specs=(spec,) * 5 + (P(),),
+                                   out_specs=(spec, spec, spec),
+                                   check_vma=False)
             self._step_fn = jax.jit(vf)
         return self._step_fn
 
@@ -384,7 +383,13 @@ class VectorizedClientEngine:
             indices = padrow(indices)
             mask = jnp.concatenate(
                 [mask, jnp.zeros((pad,) + mask.shape[1:], bool)])
-        return (stacked_params, stacked_opt_state, data, indices, mask), C
+        args = (stacked_params, stacked_opt_state, data, indices, mask)
+        if self._use_shard_map():
+            # lay each client row on its mesh device up front: the program
+            # refuses operands committed to a device outside its mesh
+            args = jax.device_put(args, NamedSharding(self.mesh,
+                                                      P(CLIENT_AXIS)))
+        return args, C
 
     def run_prepared(self, args):
         """Dispatch one padded bucket (scan or stepped); padded outputs."""
@@ -398,13 +403,19 @@ class VectorizedClientEngine:
             losses.append(loss)
         return p, s, jnp.stack(losses, axis=1)  # (C, S) like the scan's
 
-    @staticmethod
-    def finish_bucket(out, C: int):
+    def finish_bucket(self, out, C: int, home):
+        """Trim shard padding; a sharded bucket's results go back to
+        ``home``, the sharding of its start params.  Everything after
+        local training (Eq. 2, the teacher bank, KD) runs where the server
+        state lives, on one device: the chip's compiler cannot partition a
+        Pallas kernel over several devices."""
         p, s, losses = out
         if jax.tree.leaves(p)[0].shape[0] != C:  # trim shard padding
             p = jax.tree.map(lambda x: x[:C], p)
             s = jax.tree.map(lambda x: x[:C], s)
             losses = losses[:C]
+        if self._use_shard_map():
+            p, s, losses = jax.device_put((p, s, losses), home)
         return p, s, losses
 
     def scan_fn(self):
@@ -417,7 +428,8 @@ class VectorizedClientEngine:
                      stacked_opt_state: PyTree):
         """(Cb,...)-stacked params/opt state -> trained (Cb,...) stacks."""
         args, C = self.prepare_bucket(plan, stacked_params, stacked_opt_state)
-        return self.finish_bucket(self.run_prepared(args), C)
+        return self.finish_bucket(self.run_prepared(args), C,
+                                  _home(stacked_params))
 
     def train_round(self, rplan: RoundPlan, init_params_for: Callable,
                     init_opt_state_for: Callable, run_buckets=None):
@@ -451,7 +463,7 @@ class VectorizedClientEngine:
             outs = run_buckets([args for _, _, args, _ in prepared])
         buckets = []
         for (plan, w0, _, C), out in zip(prepared, outs):
-            p, s, _ = self.finish_bucket(out, C)
+            p, s, _ = self.finish_bucket(out, C, _home(w0))
             buckets.append((plan, p, s, w0))
         # reassemble in round (group-major) order: bucket rows are in
         # sorted-cid order (the data-cache key), NOT round order — the
@@ -466,6 +478,10 @@ class VectorizedClientEngine:
         group_ids = np.concatenate([b[0].group_of for b in buckets])[inv]
         sizes = np.concatenate([b[0].sizes for b in buckets])[inv]
         return stacked, group_ids, sizes, buckets
+
+
+def _home(tree):
+    return jax.tree.leaves(tree)[0].sharding
 
 
 def aggregate_groups(stacked_params: PyTree, sizes, group_ids,

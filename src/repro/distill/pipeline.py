@@ -65,13 +65,13 @@ into ``logits_fn``) fall back to the plain ``flash_kd_loss`` path.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.kd_loss import ops as kd_ops
 from repro.optim.optimizers import apply_updates, sgd
@@ -258,7 +258,7 @@ class KDPipeline:
         mesh = self.mesh
         n_dev = mesh_size(mesh)
 
-        def local_logit_sum(ts, mask, bs):
+        def local_cache(ts, mask, bs, *, M):
             # per-shard teacher forwards in ONE vmapped pass, f32 compute
             # and f32 sum (bf16-held members upcast at the boundary)
             ts = tree_cast(ts, jnp.float32)
@@ -266,11 +266,19 @@ class KDPipeline:
                 lambda b: logits_fn(p, b))(bs))(ts)            # (Ml, nB, B, V)
             lg = lg.astype(jnp.float32) * mask.reshape(
                 (-1,) + (1,) * (lg.ndim - 1))
-            return jax.lax.psum(lg.sum(0), CLIENT_AXIS)        # (nB, B, V)
-
-        sharded = shard_map(local_logit_sum, mesh=mesh,
-                            in_specs=(P(CLIENT_AXIS), P(CLIENT_AXIS), P()),
-                            out_specs=P(), check_rep=False)
+            mean = jax.lax.psum(lg.sum(0), CLIENT_AXIS)        # (nB, B, V)
+            if M is not None:
+                mean = mean / M
+            # every device finishes the cache from the replicated mean:
+            # inside the shard_map body the fused kernel sees one device's
+            # arrays, the only form the chip's compiler accepts
+            if as_logits:
+                # the psum'd logit-sum/M IS the flash cache representation
+                data = mean.astype(cache_dtype)
+                return data, kd_ops.teacher_cache_lse(data, tau)
+            # softmax(mean/τ) through the same fused kernel (M=1 stack)
+            return kd_ops.ensemble_softmax_many(mean[None], tau,
+                                                keep_pad=keep_pad)
 
         @jax.jit
         def pre(ts, bs, w=None):
@@ -281,7 +289,7 @@ class KDPipeline:
             else:
                 # normalized trust weights ride the per-member mask lane:
                 # the psum'd weighted sum IS the weighted mean (Σw = 1),
-                # so the /M renormalization is skipped below
+                # so the /M renormalization is skipped
                 wn = w.astype(jnp.float32)
                 wn = wn / jnp.maximum(wn.sum(), 1e-12)
                 mask = jnp.concatenate([wn, jnp.zeros((pad,), jnp.float32)])
@@ -290,16 +298,11 @@ class KDPipeline:
                     lambda x: jnp.concatenate(
                         [x, jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])]),
                     ts)
-            mean = sharded(ts, mask, bs)                       # (nB, B, V)
-            if w is None:
-                mean = mean / M
-            if as_logits:
-                # the psum'd logit-sum/M IS the flash cache representation
-                data = mean.astype(cache_dtype)
-                return data, kd_ops.teacher_cache_lse(data, tau)
-            # softmax(mean/τ) through the same fused kernel (M=1 stack)
-            return kd_ops.ensemble_softmax_many(mean[None], tau,
-                                                keep_pad=keep_pad)
+            sharded = jax.shard_map(
+                partial(local_cache, M=M if w is None else None), mesh=mesh,
+                in_specs=(P(CLIENT_AXIS), P(CLIENT_AXIS), P()),
+                out_specs=P(), check_vma=False)
+            return sharded(ts, mask, bs)
 
         if weighted:
             return jax.jit(lambda ts, bs, w: pre(ts, bs, w))
@@ -318,7 +321,9 @@ class KDPipeline:
         """
         if self._probs_fn is None:
             self._probs_fn = self._build_precompute("probs")
-        return self._probs_fn(teacher_stack, batches)
+        return self._to_home(self._probs_fn(*self._to_mesh(teacher_stack,
+                                                           batches)),
+                             batches)
 
     def precompute_cache(self, teacher_stack: PyTree, batches: PyTree,
                          weights=None) -> PyTree:
@@ -336,12 +341,32 @@ class KDPipeline:
         Eq. 3's uniform mean logit for the weighted combination — the
         trust-filtered ensemble target.  ``weights=None`` keeps the
         bit-identical uniform program."""
+        args = self._to_mesh(teacher_stack, batches)
         if weights is None:
-            return self._ensure_cache_fn()(teacher_stack, batches)
-        if self._cache_fn_w is None:
-            self._cache_fn_w = self._build_precompute("cache", weighted=True)
-        return self._cache_fn_w(teacher_stack, batches,
-                                jnp.asarray(weights, jnp.float32))
+            cache = self._ensure_cache_fn()(*args)
+        else:
+            if self._cache_fn_w is None:
+                self._cache_fn_w = self._build_precompute("cache",
+                                                          weighted=True)
+            cache = self._cache_fn_w(*args, jnp.asarray(weights, jnp.float32))
+        return self._to_home(cache, batches)
+
+    def _to_mesh(self, teacher_stack, batches):
+        """The sharded precompute runs on the whole mesh and refuses
+        operands committed to one device: replicate them onto the mesh
+        (the program then slices each device's teacher shard locally)."""
+        if not self._shard_teachers():
+            return teacher_stack, batches
+        return jax.device_put((teacher_stack, batches),
+                              NamedSharding(self.mesh, P()))
+
+    def _to_home(self, cache, batches):
+        """A sharded precompute leaves its cache replicated on every mesh
+        device; the KD scan runs on the server batches' one device, so
+        take that device's copy."""
+        if not self._shard_teachers():
+            return cache
+        return jax.device_put(cache, jax.tree.leaves(batches)[0].sharding)
 
     def _ensure_cache_fn(self):
         if self._cache_fn is None:

@@ -65,8 +65,9 @@ Two implementations share the algorithm:
     a block mapped to the same slot acts as carry — the same trick
     ``kernel.ensemble_softmax`` uses).
 
-VMEM budget at Bb=4, Vt=4096: two (4, 4096) f32 tiles ≈ 128 KB — live
-memory is set by the TILE, not by V; the 256 K-vocab rows never exist on
+VMEM budget: ``kernel.row_block`` sizes the (Bb, Vt) tiles to ≤ 1 MiB of
+f32 each (Bb = 64 rows at Vt=4096) — live memory is set by the TILE, not
+by V; the 256 K-vocab rows never exist on
 chip at once.  Ragged vocabularies (V not a tile multiple) need NO
 padding on any path: the Pallas grid runs ``ceil(V/Vt)`` tiles and the
 kernels mask the tail lanes in place with a ``broadcasted_iota`` column
@@ -82,9 +83,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.kd_loss.kernel import LANES, SMEM_SPEC, row_block, smem_scalar
 
-DEFAULT_BB = 4
 DEFAULT_TILE_V = 4096
 # the jnp (host) path has no VMEM budget — a wider default tile keeps the
 # XLA:CPU sweep at full vector width; explicit tile_v always wins (tests
@@ -349,8 +351,7 @@ def flash_kd_head_bwd_tiled(features, head_w, head_b, teacher_mean_logits,
 def _mask_tail(x, v_idx, v_total: int, fill):
     """Replace the ragged-tail lanes (global column ≥ v_total) with
     ``fill`` — the in-kernel ``broadcasted_iota`` mask that removes any
-    need for host-side padding (ROADMAP open item, executed).  Static
-    no-op when the tile divides V."""
+    need for host-side padding.  Static no-op when the tile divides V."""
     vt = x.shape[-1]
     if v_total % vt == 0:
         return x
@@ -420,23 +421,26 @@ def _flash_fwd_lse_kernel(s_ref, t_ref, lse_t_ref, m_s_ref, l_s_ref,
     m_s_ref[...] = m_s_new
 
     # teacher normalizer precomputed at cache build: p needs no max chain
-    p = jnp.exp(t - lse_t_ref[...][:, None])
+    p = jnp.exp(t - lse_t_ref[...])
     cross_ref[...] += jnp.sum(p * (t - s), axis=-1, keepdims=True)
 
 
-_STAT_LANES = 128   # f32 lane tile — stats blocks are (bb, 128) broadcasts
+def _lane_block(V: int, block_v: int) -> int:
+    """Vocab tile: all of V when it fits in ``block_v``, else ``block_v``
+    rounded down to the 128-lane tile (the chip refuses any other)."""
+    if V <= block_v:
+        return V
+    return max(LANES, block_v // LANES * LANES)
 
 
-def _block_b(B: int, block_b: int) -> int:
-    """Largest row block ≤ ``block_b`` dividing B (ragged batches work)."""
-    bb = max(1, min(block_b, B))
-    while B % bb:
-        bb -= 1
-    return bb
+def _col(x):
+    """(B,) per-row residual -> the (B, 1) column its blocks are cut from
+    (a 1-D block must be a multiple of 128 long; a (Bb, 1) one is legal)."""
+    return x.astype(jnp.float32).reshape(-1, 1)
 
 
 def flash_kd_fwd(student_logits, teacher_mean_logits,
-                 temperature: float = 1.0, block_b: int = DEFAULT_BB,
+                 temperature: float = 1.0,
                  block_v: int = DEFAULT_TILE_V, interpret: bool = True,
                  teacher_lse=None):
     """Fused streaming KD forward; any V works — a tile-unaligned vocab
@@ -447,38 +451,33 @@ def flash_kd_fwd(student_logits, teacher_mean_logits,
     teacher's online max/rescale chain: 3 accumulators instead of 5.
     """
     B, V = student_logits.shape
-    bb = _block_b(B, block_b)
-    vt = min(block_v, V)
-    stat = functools.partial(pl.BlockSpec, (bb, _STAT_LANES),
-                             lambda b, v: (b, 0))
+    vt = _lane_block(V, block_v)
+    bb = row_block(B, vt)
+    grid = (B // bb, pl.cdiv(V, vt))
+    tile = pl.BlockSpec((bb, vt), lambda b, v: (b, v))
+    stat = pl.BlockSpec((bb, LANES), lambda b, v: (b, 0))
+    n_stats = 5 if teacher_lse is None else 3
+    call = functools.partial(
+        pl.pallas_call, grid=grid,
+        out_specs=[stat] * n_stats,
+        out_shape=[jax.ShapeDtypeStruct((B, LANES), jnp.float32)] * n_stats,
+        interpret=interpret)
     if teacher_lse is not None:
         lse_t = teacher_lse.astype(jnp.float32)
-        outs = pl.pallas_call(
+        outs = call(
             functools.partial(_flash_fwd_lse_kernel,
                               inv_temp=1.0 / temperature, v_total=V),
-            grid=(B // bb, pl.cdiv(V, vt)),
-            in_specs=[pl.BlockSpec((bb, vt), lambda b, v: (b, v)),
-                      pl.BlockSpec((bb, vt), lambda b, v: (b, v)),
-                      pl.BlockSpec((bb,), lambda b, v: (b,))],
-            out_specs=[stat() for _ in range(3)],
-            out_shape=[jax.ShapeDtypeStruct((B, _STAT_LANES), jnp.float32)
-                       for _ in range(3)],
-            interpret=interpret,
-        )(student_logits, teacher_mean_logits, lse_t)
+            in_specs=[tile, tile,
+                      pl.BlockSpec((bb, 1), lambda b, v: (b, 0))],
+        )(student_logits, teacher_mean_logits, _col(lse_t))
         m_s, l_s, cross = (o[:, 0] for o in outs)
         lse_s = m_s + jnp.log(l_s)
         kl = cross - lse_t + lse_s
         return jnp.mean(kl) * temperature ** 2, lse_s, lse_t
-    outs = pl.pallas_call(
+    outs = call(
         functools.partial(_flash_fwd_kernel, inv_temp=1.0 / temperature,
                           v_total=V),
-        grid=(B // bb, pl.cdiv(V, vt)),
-        in_specs=[pl.BlockSpec((bb, vt), lambda b, v: (b, v)),
-                  pl.BlockSpec((bb, vt), lambda b, v: (b, v))],
-        out_specs=[stat() for _ in range(5)],
-        out_shape=[jax.ShapeDtypeStruct((B, _STAT_LANES), jnp.float32)
-                   for _ in range(5)],
-        interpret=interpret,
+        in_specs=[tile, tile],
     )(student_logits, teacher_mean_logits)
     m_s, l_s, m_t, l_t, acc = (o[:, 0] for o in outs)
     lse_s = m_s + jnp.log(l_s)
@@ -492,33 +491,31 @@ def _flash_bwd_kernel(s_ref, t_ref, lse_s_ref, lse_t_ref, g_ref, o_ref, *,
     v = pl.program_id(1)
     s = _mask_tail(s_ref[...].astype(jnp.float32), v, v_total, FLASH_PAD)
     t = _mask_tail(t_ref[...].astype(jnp.float32), v, v_total, FLASH_PAD)
-    q = jnp.exp(s * inv_temp - lse_s_ref[...][:, None])
-    p = jnp.exp(t * inv_temp - lse_t_ref[...][:, None])
-    o_ref[...] = ((q - p) * (g_ref[0] * tau_over_b)).astype(o_ref.dtype)
+    q = jnp.exp(s * inv_temp - lse_s_ref[...])
+    p = jnp.exp(t * inv_temp - lse_t_ref[...])
+    o_ref[...] = ((q - p) * (g_ref[0, 0] * tau_over_b)).astype(o_ref.dtype)
 
 
 def flash_kd_bwd(student_logits, teacher_mean_logits, lse_s, lse_t, g,
-                 temperature: float = 1.0, block_b: int = DEFAULT_BB,
+                 temperature: float = 1.0,
                  block_v: int = DEFAULT_TILE_V, interpret: bool = True):
     """Second streaming pass: ∂loss/∂student_logits from saved residuals.
     Ragged-tail stores past V land in masked lanes (q = p = 0 there)."""
     B, V = student_logits.shape
-    bb = _block_b(B, block_b)
-    vt = min(block_v, V)
+    vt = _lane_block(V, block_v)
+    bb = row_block(B, vt)
+    tile = pl.BlockSpec((bb, vt), lambda b, v: (b, v))
+    col = pl.BlockSpec((bb, 1), lambda b, v: (b, 0))
     return pl.pallas_call(
         functools.partial(_flash_bwd_kernel, inv_temp=1.0 / temperature,
                           tau_over_b=temperature / B, v_total=V),
         grid=(B // bb, pl.cdiv(V, vt)),
-        in_specs=[pl.BlockSpec((bb, vt), lambda b, v: (b, v)),
-                  pl.BlockSpec((bb, vt), lambda b, v: (b, v)),
-                  pl.BlockSpec((bb,), lambda b, v: (b,)),
-                  pl.BlockSpec((bb,), lambda b, v: (b,)),
-                  pl.BlockSpec((1,), lambda b, v: (0,))],
-        out_specs=pl.BlockSpec((bb, vt), lambda b, v: (b, v)),
+        in_specs=[tile, tile, col, col, SMEM_SPEC],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((B, V), student_logits.dtype),
         interpret=interpret,
-    )(student_logits, teacher_mean_logits, lse_s, lse_t,
-      jnp.reshape(g, (1,)).astype(jnp.float32))
+    )(student_logits, teacher_mean_logits, _col(lse_s), _col(lse_t),
+      smem_scalar(g))
 
 
 # =====================================================================
@@ -529,7 +526,29 @@ def flash_kd_bwd(student_logits, teacher_mean_logits, lse_s, lse_t, g,
 # (D, Vt) head slab + one (B, Vt) cache tile and runs the MXU matmul
 # in-kernel.  That keeps every output revisit CONSECUTIVE (a TPU
 # requirement for carry blocks): ∂h accumulates across the whole grid,
-# ∂W/∂b blocks are written exactly once at their own v step.
+# ∂W/∂b blocks are written exactly once at their own v step.  The bias
+# and its gradient travel as (1, V) rows: a 2-D (1, Vt) block is legal
+# where a 1-D one would need the iota mask in one dimension.
+#
+# VMEM: the resident (B, D) blocks do not shrink with the tile, so the
+# vocab tile is cut to keep the f32 view of one (D, Vt) head slab within
+# HEAD_SLAB_BYTES (Vt = 256 at D = 2048, 128 at D ≥ 4096), and the scoped
+# VMEM limit is raised from the 16 MiB default (a v5e core has 128 MiB).
+# B·D must still fit: at D = 5120 that holds to about 1K feature rows.
+HEAD_SLAB_BYTES = 2 << 20
+HEAD_VMEM_LIMIT = 96 << 20
+
+
+def _head_call(kernel, **kw):
+    return pl.pallas_call(
+        kernel, compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=HEAD_VMEM_LIMIT), **kw)
+
+
+def _head_block_v(V: int, D: int, block_v: int) -> int:
+    cap = max(LANES, HEAD_SLAB_BYTES // (4 * D) // LANES * LANES)
+    return _lane_block(V, min(block_v, cap))
+
 
 def _head_tile(h, w_ref, b_ref, v, v_total: int):
     """(B, vt) student tile ``h @ W_tile (+ b_tile)`` with masked-lane
@@ -537,8 +556,7 @@ def _head_tile(h, w_ref, b_ref, v, v_total: int):
     w = _mask_tail(w_ref[...].astype(jnp.float32), v, v_total, 0.0)
     s = jnp.dot(h, w, preferred_element_type=jnp.float32)
     if b_ref is not None:
-        s = s + _mask_tail(b_ref[...].astype(jnp.float32), v, v_total,
-                           0.0)[None, :]
+        s = s + _mask_tail(b_ref[...].astype(jnp.float32), v, v_total, 0.0)
     return _mask_tail(s, v, v_total, FLASH_PAD)
 
 
@@ -601,8 +619,16 @@ def _flash_head_fwd_lse_kernel(h_ref, w_ref, b_ref, t_ref, lse_t_ref,
                               keepdims=True))
     m_s_ref[...] = m_s_new
 
-    p = jnp.exp(t - lse_t_ref[...][:, None])
+    p = jnp.exp(t - lse_t_ref[...])
     cross_ref[...] += jnp.sum(p * (t - s), axis=-1, keepdims=True)
+
+
+def _without_bias(kern, trailing=()):
+    """Kernel adapter for the no-bias call: ``None`` for ``b_ref`` (the
+    third input) and for the ``trailing`` refs the kernel expects after
+    the real ones (the backward's bias-gradient output)."""
+    return lambda h_ref, w_ref, *rest, **kw: kern(h_ref, w_ref, None, *rest,
+                                                  *trailing, **kw)
 
 
 def flash_kd_head_fwd(features, head_w, head_b, teacher_mean_logits,
@@ -613,51 +639,37 @@ def flash_kd_head_fwd(features, head_w, head_b, teacher_mean_logits,
     logit row exists only as the in-kernel ``(B, vt)`` MXU product."""
     B, D = features.shape
     V = teacher_mean_logits.shape[-1]
-    vt = min(block_v, V)
-    grid = (pl.cdiv(V, vt),)
-    stat = functools.partial(pl.BlockSpec, (B, _STAT_LANES),
-                             lambda v: (0, 0))
+    vt = _head_block_v(V, D, block_v)
+    stat = pl.BlockSpec((B, LANES), lambda v: (0, 0))
     in_specs = [pl.BlockSpec((B, D), lambda v: (0, 0)),
                 pl.BlockSpec((D, vt), lambda v: (0, v))]
     operands = [features, head_w]
     if head_b is not None:
-        in_specs.append(pl.BlockSpec((vt,), lambda v: (v,)))
-        operands.append(head_b)
+        in_specs.append(pl.BlockSpec((1, vt), lambda v: (0, v)))
+        operands.append(head_b.reshape(1, V))
     in_specs.append(pl.BlockSpec((B, vt), lambda v: (0, v)))
     operands.append(teacher_mean_logits)
-
-    def with_bias(kern):
-        if head_b is not None:
-            return kern
-        return lambda h_ref, w_ref, *rest, **kw: kern(h_ref, w_ref, None,
-                                                      *rest, **kw)
-
     if teacher_lse is not None:
         lse_t = teacher_lse.astype(jnp.float32)
-        in_specs.append(pl.BlockSpec((B,), lambda v: (0,)))
-        operands.append(lse_t)
-        outs = pl.pallas_call(
-            functools.partial(with_bias(_flash_head_fwd_lse_kernel),
-                              inv_temp=1.0 / temperature, v_total=V),
-            grid=grid, in_specs=in_specs,
-            out_specs=[stat() for _ in range(3)],
-            out_shape=[jax.ShapeDtypeStruct((B, _STAT_LANES), jnp.float32)
-                       for _ in range(3)],
-            interpret=interpret,
-        )(*operands)
+        in_specs.append(pl.BlockSpec((B, 1), lambda v: (0, 0)))
+        operands.append(_col(lse_t))
+    kern = (_flash_head_fwd_kernel if teacher_lse is None
+            else _flash_head_fwd_lse_kernel)
+    n_stats = 5 if teacher_lse is None else 3
+    if head_b is None:
+        kern = _without_bias(kern)
+    outs = _head_call(
+        functools.partial(kern, inv_temp=1.0 / temperature, v_total=V),
+        grid=(pl.cdiv(V, vt),), in_specs=in_specs,
+        out_specs=[stat] * n_stats,
+        out_shape=[jax.ShapeDtypeStruct((B, LANES), jnp.float32)] * n_stats,
+        interpret=interpret,
+    )(*operands)
+    if teacher_lse is not None:
         m_s, l_s, cross = (o[:, 0] for o in outs)
         lse_s = m_s + jnp.log(l_s)
         kl = cross - lse_t + lse_s
         return jnp.mean(kl) * temperature ** 2, lse_s, lse_t
-    outs = pl.pallas_call(
-        functools.partial(with_bias(_flash_head_fwd_kernel),
-                          inv_temp=1.0 / temperature, v_total=V),
-        grid=grid, in_specs=in_specs,
-        out_specs=[stat() for _ in range(5)],
-        out_shape=[jax.ShapeDtypeStruct((B, _STAT_LANES), jnp.float32)
-                   for _ in range(5)],
-        interpret=interpret,
-    )(*operands)
     m_s, l_s, m_t, l_t, acc = (o[:, 0] for o in outs)
     lse_s = m_s + jnp.log(l_s)
     lse_t = m_t + jnp.log(l_t)
@@ -678,21 +690,21 @@ def _flash_head_bwd_kernel(h_ref, w_ref, b_ref, t_ref, lse_s_ref, lse_t_ref,
     w = _mask_tail(w_ref[...].astype(jnp.float32), v, v_total, 0.0)
     s = jnp.dot(h, w, preferred_element_type=jnp.float32)
     if b_ref is not None:
-        s = s + _mask_tail(b_ref[...].astype(jnp.float32), v, v_total,
-                           0.0)[None, :]
+        s = s + _mask_tail(b_ref[...].astype(jnp.float32), v, v_total, 0.0)
     s = _mask_tail(s, v, v_total, FLASH_PAD)
     t = _mask_tail(t_ref[...].astype(jnp.float32), v, v_total, FLASH_PAD)
-    q = jnp.exp(s * inv_temp - lse_s_ref[...][:, None])
-    p = jnp.exp(t * inv_temp - lse_t_ref[...][:, None])
-    d = (q - p) * (g_ref[0] * tau_over_b)       # (B, vt) — THE only width
+    q = jnp.exp(s * inv_temp - lse_s_ref[...])
+    p = jnp.exp(t * inv_temp - lse_t_ref[...])
+    d = (q - p) * (g_ref[0, 0] * tau_over_b)    # (B, vt) — THE only width
     #                                             the logit grad ever has
     # ∂h accumulates across the v sweep (masked lanes: d = 0, w = 0)
-    gh_ref[...] += jnp.dot(d, w.T, preferred_element_type=jnp.float32)
-    gw_ref[...] = jnp.dot(h.T, d,
-                          preferred_element_type=jnp.float32).astype(
-        gw_ref.dtype)
+    gh_ref[...] += jax.lax.dot_general(
+        d, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    gw_ref[...] = jax.lax.dot_general(
+        h, d, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(gw_ref.dtype)
     if gb_ref is not None:
-        gb_ref[...] = jnp.sum(d, axis=0).astype(gb_ref.dtype)
+        gb_ref[...] = jnp.sum(d, axis=0, keepdims=True).astype(gb_ref.dtype)
 
 
 def flash_kd_head_bwd(features, head_w, head_b, teacher_mean_logits,
@@ -702,43 +714,35 @@ def flash_kd_head_bwd(features, head_w, head_b, teacher_mean_logits,
     one streaming V sweep, ∂h carried in a revisited f32 block."""
     B, D = features.shape
     V = teacher_mean_logits.shape[-1]
-    vt = min(block_v, V)
-    grid = (pl.cdiv(V, vt),)
+    vt = _head_block_v(V, D, block_v)
+    col = pl.BlockSpec((B, 1), lambda v: (0, 0))
     in_specs = [pl.BlockSpec((B, D), lambda v: (0, 0)),
                 pl.BlockSpec((D, vt), lambda v: (0, v))]
     operands = [features, head_w]
     if head_b is not None:
-        in_specs.append(pl.BlockSpec((vt,), lambda v: (v,)))
-        operands.append(head_b)
-    in_specs += [pl.BlockSpec((B, vt), lambda v: (0, v)),
-                 pl.BlockSpec((B,), lambda v: (0,)),
-                 pl.BlockSpec((B,), lambda v: (0,)),
-                 pl.BlockSpec((1,), lambda v: (0,))]
-    operands += [teacher_mean_logits, lse_s, lse_t,
-                 jnp.reshape(g, (1,)).astype(jnp.float32)]
+        in_specs.append(pl.BlockSpec((1, vt), lambda v: (0, v)))
+        operands.append(head_b.reshape(1, V))
+    in_specs += [pl.BlockSpec((B, vt), lambda v: (0, v)), col, col,
+                 SMEM_SPEC]
+    operands += [teacher_mean_logits, _col(lse_s), _col(lse_t),
+                 smem_scalar(g)]
     out_specs = [pl.BlockSpec((B, D), lambda v: (0, 0)),
                  pl.BlockSpec((D, vt), lambda v: (0, v))]
     out_shape = [jax.ShapeDtypeStruct((B, D), jnp.float32),
                  jax.ShapeDtypeStruct((D, V), head_w.dtype)]
     if head_b is not None:
-        out_specs.append(pl.BlockSpec((vt,), lambda v: (v,)))
-        out_shape.append(jax.ShapeDtypeStruct((V,), head_b.dtype))
-
+        out_specs.append(pl.BlockSpec((1, vt), lambda v: (0, v)))
+        out_shape.append(jax.ShapeDtypeStruct((1, V), head_b.dtype))
     kern = _flash_head_bwd_kernel
     if head_b is None:
-        def kern(h_ref, w_ref, t_ref, ls_ref, lt_ref, g_ref, gh_ref,
-                 gw_ref, **kw):
-            return _flash_head_bwd_kernel(h_ref, w_ref, None, t_ref, ls_ref,
-                                          lt_ref, g_ref, gh_ref, gw_ref,
-                                          None, **kw)
-
-    outs = pl.pallas_call(
+        kern = _without_bias(kern, trailing=(None,))
+    outs = _head_call(
         functools.partial(kern, inv_temp=1.0 / temperature,
                           tau_over_b=temperature / B, v_total=V),
-        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        grid=(pl.cdiv(V, vt),), in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret,
     )(*operands)
     gh = outs[0].astype(features.dtype)
     gw = outs[1]
-    gb = outs[2] if head_b is not None else None
+    gb = outs[2].reshape(V) if head_b is not None else None
     return gh, gw, gb

@@ -1,9 +1,10 @@
 """Public jit'd KD ops with custom_vjp and backend dispatch.
 
-On TPU the Pallas kernels run compiled; elsewhere they run in interpret
-mode only when ``REPRO_FORCE_PALLAS=1`` (tests do this) — the default
-CPU path is the jnp oracle, which lowers to identical math for the
-dry-run's cost analysis.
+On a TPU backend the Pallas kernels run compiled (Mosaic).  On any other
+backend the ops run the pure-jnp implementations — ``ref.py`` for the
+dense family, the tiled jnp sweeps of ``flash.py`` for the flash family
+— unless ``REPRO_FORCE_PALLAS=1``, which runs the same Pallas kernels in
+interpret mode (the CPU tests use it to hold the kernels to ``ref.py``).
 
 Two KD kernel families live here:
 

@@ -47,7 +47,7 @@ def weighted_average(stacked: jnp.ndarray, weights: jnp.ndarray,
 def _multi_wavg_kernel(w_ref, x_ref, o_ref):
     x = x_ref[0].astype(jnp.float32)                # (N, Db)
     w = w_ref[0].astype(jnp.float32)                # (N, 1)
-    o_ref[...] = jnp.sum(x * w, axis=0, keepdims=True).astype(o_ref.dtype)
+    o_ref[0] = jnp.sum(x * w, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def multi_weighted_average(stacked: jnp.ndarray, weights: jnp.ndarray,
@@ -60,18 +60,24 @@ def multi_weighted_average(stacked: jnp.ndarray, weights: jnp.ndarray,
     column (normalized per group on the host side of the call — tiny) and
     reduces on the VPU.  HBM traffic stays at the streaming optimum
     G·N·D reads + G·D writes with no (G, N, D) temporaries.
+
+    The output is produced as (G, 1, D) and reshaped: a (1, Db) block of
+    a (G, D) array has a second-minor dim (1) that is neither a multiple
+    of 8 nor G, which the chip's compiler refuses; over (G, 1, D) the
+    same block's last two dims are (1 = the array's, Db).
     """
     G, N, D = stacked.shape
     db = min(block_d, D)
     assert D % db == 0, (D, db)
     w = weights.astype(jnp.float32)
     w = w / jnp.sum(w, axis=1, keepdims=True)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _multi_wavg_kernel,
         grid=(G, D // db),
         in_specs=[pl.BlockSpec((1, N, 1), lambda g, d: (g, 0, 0)),
                   pl.BlockSpec((1, N, db), lambda g, d: (g, 0, d))],
-        out_specs=pl.BlockSpec((1, db), lambda g, d: (g, d)),
-        out_shape=jax.ShapeDtypeStruct((G, D), stacked.dtype),
+        out_specs=pl.BlockSpec((1, 1, db), lambda g, d: (g, 0, d)),
+        out_shape=jax.ShapeDtypeStruct((G, 1, D), stacked.dtype),
         interpret=interpret,
     )(w[:, :, None], stacked)
+    return out.reshape(G, D)

@@ -1,8 +1,16 @@
-"""Public weighted-average ops: 2-D entry point + whole-pytree wrapper used
-by ``core.aggregation`` on TPU."""
+"""Public weighted-average ops: 2-D entry points + whole-pytree wrappers
+used by ``core.aggregation`` on TPU.
+
+The pytree wrappers are one jitted program per tree structure and leaf
+shapes: a ``pallas_call`` made outside ``jit`` builds a new callable on
+every call and so compiles again on every call, which for a ResNet's
+~60 leaves was ~60 kernel compiles per round.  The backend dispatch is
+decided outside the trace and passed in as static arguments.
+"""
 from __future__ import annotations
 
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +28,9 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def weighted_average(stacked, weights, block_d: int | None = None):
-    """stacked (N, D), weights (N,) -> (D,)."""
-    if not _use_pallas():
+def _weighted_average(stacked, weights, pallas: bool, interpret: bool,
+                      block_d: int | None = None):
+    if not pallas:
         return ref.weighted_average_ref(stacked, weights)
     N, D = stacked.shape
     db = block_d or min(kernel.DEFAULT_DB, max(128, D))
@@ -30,25 +38,35 @@ def weighted_average(stacked, weights, block_d: int | None = None):
     if pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
     out = kernel.weighted_average(stacked, weights, block_d=db,
-                                  interpret=_interpret())
+                                  interpret=interpret)
     return out[:D]
 
 
-def weighted_average_pytree(stacked_tree, weights):
-    """Leaves with leading client axis (N, ...) -> averaged leaves (...)."""
+def weighted_average(stacked, weights, block_d: int | None = None):
+    """stacked (N, D), weights (N,) -> (D,)."""
+    return _weighted_average(stacked, weights, _use_pallas(), _interpret(),
+                             block_d)
 
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _weighted_average_tree(stacked_tree, weights, pallas, interpret):
     def leaf(x):
         N = x.shape[0]
-        flat = x.reshape(N, -1)
-        return weighted_average(flat, weights).reshape(x.shape[1:])
+        return _weighted_average(x.reshape(N, -1), weights, pallas,
+                                 interpret).reshape(x.shape[1:])
 
     return jax.tree.map(leaf, stacked_tree)
 
 
-def group_weighted_average(stacked, weights, block_d: int | None = None):
-    """Batched multi-model path: stacked (G, N, D), weights (G, N) ->
-    (G, D) — all G group averages in one fused pass."""
-    if not _use_pallas():
+def weighted_average_pytree(stacked_tree, weights):
+    """Leaves with leading client axis (N, ...) -> averaged leaves (...)."""
+    return _weighted_average_tree(stacked_tree, weights, _use_pallas(),
+                                  _interpret())
+
+
+def _group_weighted_average(stacked, weights, pallas: bool, interpret: bool,
+                            block_d: int | None = None):
+    if not pallas:
         return ref.group_weighted_average_ref(stacked, weights)
     _, _, D = stacked.shape
     db = block_d or min(kernel.DEFAULT_DB, max(128, D))
@@ -56,17 +74,29 @@ def group_weighted_average(stacked, weights, block_d: int | None = None):
     if pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, 0), (0, pad)))
     out = kernel.multi_weighted_average(stacked, weights, block_d=db,
-                                        interpret=_interpret())
+                                        interpret=interpret)
     return out[:, :D]
+
+
+def group_weighted_average(stacked, weights, block_d: int | None = None):
+    """Batched multi-model path: stacked (G, N, D), weights (G, N) ->
+    (G, D) — all G group averages in one fused pass."""
+    return _group_weighted_average(stacked, weights, _use_pallas(),
+                                   _interpret(), block_d)
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _group_weighted_average_tree(stacked_tree, weights, pallas, interpret):
+    def leaf(x):
+        G, N = x.shape[:2]
+        return _group_weighted_average(
+            x.reshape(G, N, -1), weights, pallas,
+            interpret).reshape((G,) + x.shape[2:])
+
+    return jax.tree.map(leaf, stacked_tree)
 
 
 def group_weighted_average_pytree(stacked_tree, weights):
     """Leaves with leading (G, N, ...) axes -> averaged leaves (G, ...)."""
-
-    def leaf(x):
-        G, N = x.shape[:2]
-        flat = x.reshape(G, N, -1)
-        return group_weighted_average(flat, weights).reshape(
-            (G,) + x.shape[2:])
-
-    return jax.tree.map(leaf, stacked_tree)
+    return _group_weighted_average_tree(stacked_tree, weights, _use_pallas(),
+                                        _interpret())

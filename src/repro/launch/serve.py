@@ -25,11 +25,13 @@ import numpy as np
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.data.synthetic import make_model_batch
 from repro.fedckpt.checkpointer import load_pytree
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import ContinuousEngine, Request, generate_static, run_closed_loop
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b", choices=list(ASSIGNED_ARCHS))
     ap.add_argument("--ckpt", default=None, help="npz checkpoint to serve")

@@ -23,9 +23,11 @@ from repro.core.faults import FaultPlan
 from repro.core.fedsdd import PRESETS, make_runner
 from repro.core.tasks import classification_task, lm_task
 from repro.fedckpt.checkpointer import Checkpointer
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="fedsdd", choices=sorted(PRESETS))
     ap.add_argument("--model", default="cnn",

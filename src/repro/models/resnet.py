@@ -10,7 +10,6 @@ statistics (stats averaged like any other state) for completeness.
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -110,11 +109,15 @@ def resnet_loss(params, batch, cfg: ResNetConfig):
     return loss, {"acc": acc}
 
 
+# one program per (cfg, batch shape) for the whole process: a jit built
+# inside resnet_accuracy would compile again on every evaluation
+_logits_jit = jax.jit(resnet_logits, static_argnames="cfg")
+
+
 def resnet_accuracy(params, x, y, cfg: ResNetConfig, batch: int = 500):
     """Full-set accuracy evaluated in minibatches."""
     hits = 0
-    fwd = jax.jit(partial(resnet_logits, cfg=cfg))
     for i in range(0, len(x), batch):
-        logits = fwd(params, jnp.asarray(x[i:i + batch]))
+        logits = _logits_jit(params, jnp.asarray(x[i:i + batch]), cfg)
         hits += int(jnp.sum(jnp.argmax(logits, -1) == jnp.asarray(y[i:i + batch])))
     return hits / len(x)

@@ -85,6 +85,24 @@ def test_weight_avg_uniform_weights_is_mean():
     np.testing.assert_allclose(np.asarray(got), np.asarray(x.mean(0)), atol=1e-5)
 
 
+@pytest.mark.parametrize("grouped", [False, True])
+def test_weight_avg_pytree_compiles_once(grouped):
+    """A second call at the same shapes reuses the compiled program: a
+    pallas_call made outside jit compiled again on every call, once per
+    model leaf per round."""
+    from repro.analysis import TraceGuard
+    from repro.kernels.weight_avg import ops
+    lead = (2, 3) if grouped else (3,)
+    tree = {"a": jnp.ones(lead + (5,)), "b": jnp.ones(lead + (7, 4))}
+    w = jnp.arange(1.0, 1.0 + np.prod(lead)).reshape(lead)
+    fn = (ops.group_weighted_average_pytree if grouped
+          else ops.weighted_average_pytree)
+    jax.block_until_ready(fn(tree, w))
+    with TraceGuard("weight_avg pytree") as guard:
+        jax.block_until_ready(fn(tree, w))
+    guard.assert_steady_state()
+
+
 # ------------------------------------------------------------ flash attention
 @pytest.mark.parametrize("B,S,H,Hkv,dh", [
     (2, 256, 4, 2, 64), (1, 128, 8, 1, 32), (2, 256, 4, 4, 128),

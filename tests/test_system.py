@@ -87,3 +87,8 @@ def test_resnet20_paper_model_trains():
                     distill_steps=2, server_lr=0.05)
     st = r.run(rounds=1)
     assert np.isfinite(st.history[-1]["acc_main"])
+    # the round evaluated once already: evaluating again compiles nothing
+    from repro.analysis import TraceGuard
+    with TraceGuard("resnet eval") as guard:
+        task.eval_fn(st.global_models[0])
+    guard.assert_steady_state()
