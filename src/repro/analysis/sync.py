@@ -44,6 +44,8 @@ from typing import Iterator
 
 import jax
 
+from repro.analysis.spans import span
+
 __all__ = ["SyncViolation", "allowed_sync", "sync_contract"]
 
 
@@ -136,13 +138,15 @@ def allowed_sync(reason: str) -> Iterator[None]:
     Inside the scope the portable funnel and the jax transfer guard both
     stand down (this thread only).  The linter treats the lexical scope
     as exempt from RA101, so the one-line justification lives exactly
-    where the sync happens.
+    where the sync happens.  The scope is the program span
+    ``fedsdd.sync``, with the reason as its attribute.
     """
     if not reason or not reason.strip():
         raise ValueError("allowed_sync requires a non-empty reason string")
     _TLS.depth = _allow_depth() + 1
     try:
-        with jax.transfer_guard_device_to_host("allow"):
+        with span("fedsdd.sync", reason=reason), \
+                jax.transfer_guard_device_to_host("allow"):
             yield
     finally:
         _TLS.depth = _allow_depth() - 1

@@ -38,12 +38,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.analysis.spans import count, named_program, span
 from repro.core.grouping import group_major_order
 from repro.optim.optimizers import Optimizer, apply_updates
 from repro.sharding.specs import CLIENT_AXIS
 from repro.utils.pytree import tree_stack, tree_unstack, tree_where
 
 PyTree = Any
+
+# the stable name of the bucket-scan program in a profiler trace
+BUCKET_SCAN = "fedsdd_bucket_scan"
 
 
 # =====================================================================
@@ -330,7 +334,7 @@ class VectorizedClientEngine:
                                    in_specs=(spec,) * 5,
                                    out_specs=(spec, spec, spec),
                                    check_vma=False)
-            self._vec_fn = jax.jit(vf)
+            self._vec_fn = named_program(BUCKET_SCAN, vf)
         return self._vec_fn
 
     def _stepped_fn(self):
@@ -343,7 +347,7 @@ class VectorizedClientEngine:
                                    in_specs=(spec,) * 5 + (P(),),
                                    out_specs=(spec, spec, spec),
                                    check_vma=False)
-            self._step_fn = jax.jit(vf)
+            self._step_fn = named_program(BUCKET_SCAN, vf)
         return self._step_fn
 
     def jit_programs(self) -> dict:
@@ -452,31 +456,37 @@ class VectorizedClientEngine:
         view, since opt-state trees are stacked per bucket).
         """
         prepared = []
-        for plan in rplan.plans:
-            w0 = init_params_for(plan)
-            s0 = init_opt_state_for(plan, w0)
-            args, C = self.prepare_bucket(plan, w0, s0)
-            prepared.append((plan, w0, args, C))
-        if run_buckets is None:
-            outs = [self.run_prepared(args) for _, _, args, _ in prepared]
-        else:
-            outs = run_buckets([args for _, _, args, _ in prepared])
-        buckets = []
-        for (plan, w0, _, C), out in zip(prepared, outs):
-            p, s, _ = self.finish_bucket(out, C, _home(w0))
-            buckets.append((plan, p, s, w0))
-        # reassemble in round (group-major) order: bucket rows are in
-        # sorted-cid order (the data-cache key), NOT round order — the
-        # permutation is required even for a single bucket
-        order = np.concatenate([b[0].order for b in buckets])
-        inv = np.argsort(order)
-        perm = jnp.asarray(inv)
-        stacked = jax.tree.map(
-            lambda *xs: jnp.concatenate(xs)[perm] if len(xs) > 1
-            else xs[0][perm],
-            *[b[1] for b in buckets])
-        group_ids = np.concatenate([b[0].group_of for b in buckets])[inv]
-        sizes = np.concatenate([b[0].sizes for b in buckets])[inv]
+        with span("fedsdd.local.prep"):
+            for plan in rplan.plans:
+                w0 = init_params_for(plan)
+                s0 = init_opt_state_for(plan, w0)
+                args, C = self.prepare_bucket(plan, w0, s0)
+                prepared.append((plan, w0, args, C))
+        # rows x steps of the step masks: shard padding and padded steps
+        count("scan_steps", sum(args[4].size for _, _, args, _ in prepared))
+        with span("fedsdd.local.dispatch"):
+            if run_buckets is None:
+                outs = [self.run_prepared(args)
+                        for _, _, args, _ in prepared]
+            else:
+                outs = run_buckets([args for _, _, args, _ in prepared])
+        with span("fedsdd.local.reassemble"):
+            buckets = []
+            for (plan, w0, _, C), out in zip(prepared, outs):
+                p, s, _ = self.finish_bucket(out, C, _home(w0))
+                buckets.append((plan, p, s, w0))
+            # reassemble in round (group-major) order: bucket rows are in
+            # sorted-cid order (the data-cache key), NOT round order — the
+            # permutation is required even for a single bucket
+            order = np.concatenate([b[0].order for b in buckets])
+            inv = np.argsort(order)
+            perm = jnp.asarray(inv)
+            stacked = jax.tree.map(
+                lambda *xs: jnp.concatenate(xs)[perm] if len(xs) > 1
+                else xs[0][perm],
+                *[b[1] for b in buckets])
+            group_ids = np.concatenate([b[0].group_of for b in buckets])[inv]
+            sizes = np.concatenate([b[0].sizes for b in buckets])[inv]
         return stacked, group_ids, sizes, buckets
 
 

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.spans import collect_round, count, span
 from repro.core import distillation as dist
 from repro.core import engine as vec_engine
 from repro.core import faults as faults_lib
@@ -509,16 +510,28 @@ class FederatedRunner:
         """One round as an explicit phase plan (core/round_plan.py): the
         executor owns phase ordering + the deferred-KD state machine, the
         per-engine ops adapter below owns the engine-native phase bodies.
+
+        The round's program spans and counters (``analysis/spans.py``)
+        land in its history record as ``spans`` (host seconds by span),
+        ``span_parents`` (the same seconds by enclosing span) and
+        ``counts``.
         """
         cfg = self.cfg
         t = state.round + 1
-        rng = np.random.default_rng(cfg.seed * 100_000 + t)
-        active = sample_clients(cfg.num_clients, cfg.participation, rng)
-        groups = assign_groups(active, cfg.K, rng)
-        ops_cls = (_VectorizedRoundOps if cfg.execution == "vectorized"
-                   else _SequentialRoundOps)
-        ops = ops_cls(self, state, groups, rng, t)
-        return self._executor().execute(state, t, len(active), ops)
+        with collect_round(round=t) as col, span("fedsdd.round"):
+            with span("fedsdd.sample"):
+                rng = np.random.default_rng(cfg.seed * 100_000 + t)
+                active = sample_clients(cfg.num_clients, cfg.participation,
+                                        rng)
+                groups = assign_groups(active, cfg.K, rng)
+                ops_cls = (_VectorizedRoundOps
+                           if cfg.execution == "vectorized"
+                           else _SequentialRoundOps)
+                ops = ops_cls(self, state, groups, rng, t)
+            state = self._executor().execute(state, t, len(active), ops)
+        state.history[-1].update(spans=col.seconds, span_parents=col.parents,
+                                 counts=col.counts)
+        return state
 
     def finalize(self, state: FedState) -> FedState:
         """Drain the deferred KD job (overlap modes).  After this the
@@ -980,14 +993,17 @@ class _VectorizedRoundOps:
             return
         runner, state, cfg = self.runner, self.state, self.runner.cfg
         store = self.store
+        count("local_steps", sum(len(e.idx) for e in ents if not e.dropped))
         # pin this phase's clients resident while their bucket stacks are
         # assembled and consumed — the O(sampled) residency contract
         with store.sampled_view([e.cid for e in ents]) as view:
-            rplan = vec_engine.plan_from_entries(
-                runner.task, ents, self.groups, store=store,
-                pad_to=self.pad_hints)
+            with span("fedsdd.local.prep"):
+                rplan = vec_engine.plan_from_entries(
+                    runner.task, ents, self.groups, store=store,
+                    pad_to=self.pad_hints)
+                # (K, ...) per phase
+                stacked_k = tree_stack(state.global_models)
             optimizer = self.eng.optimizer
-            stacked_k = tree_stack(state.global_models)  # (K, ...) per phase
 
             def init_params_for(plan):
                 gid = jnp.asarray(plan.group_of)
@@ -1069,15 +1085,22 @@ class _VectorizedRoundOps:
         if len(self.results) == 1:
             stacked, gids, sizes, _, cids = self.results[0]
         else:
-            orders = np.concatenate([r[3] for r in self.results])
-            inv = np.argsort(orders)
-            perm = jnp.asarray(inv)
-            stacked = jax.tree.map(
-                lambda *xs: jnp.concatenate(xs)[perm],
-                *[r[0] for r in self.results])
-            gids = np.concatenate([r[1] for r in self.results])[inv]
-            sizes = np.concatenate([r[2] for r in self.results])[inv]
-            cids = np.concatenate([r[4] for r in self.results])[inv]
+            with span("fedsdd.local.reassemble"):
+                orders = np.concatenate([r[3] for r in self.results])
+                inv = np.argsort(orders)
+                perm = jnp.asarray(inv)
+                stacked = jax.tree.map(
+                    lambda *xs: jnp.concatenate(xs)[perm],
+                    *[r[0] for r in self.results])
+                gids = np.concatenate([r[1] for r in self.results])[inv]
+                sizes = np.concatenate([r[2] for r in self.results])[inv]
+                cids = np.concatenate([r[4] for r in self.results])[inv]
+        with span("fedsdd.eq2"):
+            return self._eq2(stacked, gids, sizes, cids)
+
+    def _eq2(self, stacked, gids, sizes, cids) -> list[PyTree]:
+        """Eq. 2 (plain, masked or robust) over the round-ordered stack,
+        unstacked into the K new global models."""
         self.stacked_clients, self.sizes = stacked, sizes
         self.cids_round = cids
         rf, cfg = self.faults, self.runner.cfg
@@ -1139,16 +1162,13 @@ class _VectorizedRoundOps:
         return teacher_stack
 
     def inline_kd(self, new_globals) -> dict:
-        cfg, runner, state = self.runner.cfg, self.runner, self.state
-        if cfg.ensemble_source == "clients":
-            teacher_stack = self._client_teacher_stack(new_globals)
-        else:
-            teacher_stack = state.ensemble.members_stacked()
+        runner, state = self.runner, self.state
+        with span("fedsdd.kd.teachers"):
+            teacher_stack = self.kd_teachers(new_globals)
+            weights = runner._teacher_trust_weights(state, teacher_stack)
         return runner._distill_models(
             new_globals, teacher_stack, stacked=True,
-            stacked_students=self.stacked_globals,
-            teacher_weights=runner._teacher_trust_weights(
-                state, teacher_stack))
+            stacked_students=self.stacked_globals, teacher_weights=weights)
 
     def kd_teachers(self, new_globals) -> PyTree:
         if self.runner.cfg.ensemble_source == "clients":

@@ -65,6 +65,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.spans import span
 from repro.analysis.sync import allowed_sync
 
 PyTree = Any
@@ -306,18 +307,19 @@ class RoundExecutor:
             new_globals = ops.aggregate()
             rec.update(getattr(ops, "fault_info", {}))
             ops.push(t, state)
-            jax.block_until_ready(jax.tree.leaves(new_globals[0])[0])
+            with span("fedsdd.wait.local"):
+                jax.block_until_ready(jax.tree.leaves(new_globals[0])[0])
             rec["t_local"] = time.perf_counter() - t_start
             if self.kd_active(t):
                 t0 = time.perf_counter()
                 rec.update(ops.inline_kd(new_globals))
-                jax.block_until_ready(jax.tree.leaves(new_globals[0])[0])
+                with span("fedsdd.wait.kd"):
+                    jax.block_until_ready(jax.tree.leaves(new_globals[0])[0])
                 rec["t_kd"] = time.perf_counter() - t0
             state.global_models = new_globals
             if task.eval_fn is not None:
                 with allowed_sync("per-round eval of the main model"):
                     rec["acc_main"] = task.eval_fn(new_globals[0])
-            rec["t_round"] = time.perf_counter() - t_start
             state.history.append(rec)
             state.round = t
             return state
@@ -355,17 +357,16 @@ class RoundExecutor:
         if self.kd_active(t):
             # emit round t's KD as a pending job; async dispatches NOW so
             # the program overlaps the host-side planning of round t+1 too
-            teachers = ops.kd_teachers(new_globals)
+            with span("fedsdd.kd.teachers"):
+                teachers = ops.kd_teachers(new_globals)
+                weights = self.runner._teacher_trust_weights(state, teachers)
             state.pending_kd = PendingKD(
                 round_idx=t, student=new_globals[0],
-                teachers=teachers, record=rec,
-                teacher_weights=self.runner._teacher_trust_weights(
-                    state, teachers))
+                teachers=teachers, record=rec, teacher_weights=weights)
             if cfg.overlap == "async":
                 self.dispatch(state.pending_kd)
         elif task.eval_fn is not None:
             with allowed_sync("per-round eval of the main model"):
                 rec["acc_main"] = task.eval_fn(new_globals[0])
-        rec["t_round"] = time.perf_counter() - t_start
         state.history.append(rec)
         return state

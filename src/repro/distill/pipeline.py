@@ -73,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.analysis.spans import named_program, span
 from repro.kernels.kd_loss import ops as kd_ops
 from repro.optim.optimizers import apply_updates, sgd
 from repro.sharding.specs import CLIENT_AXIS
@@ -88,6 +89,10 @@ LogitsFn = Callable[[PyTree, Any], jnp.ndarray]
 # are discounted before normalization.
 TRUST_FLOOR = 0.1
 TRUST_DEGRADED_DISCOUNT = 0.5
+
+# stable names of the KD programs in a profiler trace
+PRECOMPUTE = "fedsdd_kd_precompute"
+KD_SCAN = "fedsdd_kd_scan"
 
 
 def stack_server_batches(batches: Sequence[Any]) -> PyTree:
@@ -223,7 +228,7 @@ class KDPipeline:
         keep_pad = kind == "cache" and kd_ops.pallas_active()
         cache_dtype = self.cache_dtype
         if not self._shard_teachers():
-            @jax.jit
+            @partial(named_program, PRECOMPUTE)
             def pre(ts, bs, w=None):
                 # f32 compute regardless of bank storage dtype: bf16-held
                 # members upcast at the forward boundary (XLA fuses the
@@ -251,7 +256,8 @@ class KDPipeline:
                                                     keep_pad=keep_pad)
 
             if weighted:
-                return jax.jit(lambda ts, bs, w: pre(ts, bs, w))
+                return named_program(PRECOMPUTE,
+                                     lambda ts, bs, w: pre(ts, bs, w))
             return pre
 
         from repro.launch.mesh import mesh_size
@@ -280,7 +286,7 @@ class KDPipeline:
             return kd_ops.ensemble_softmax_many(mean[None], tau,
                                                 keep_pad=keep_pad)
 
-        @jax.jit
+        @partial(named_program, PRECOMPUTE)
         def pre(ts, bs, w=None):
             M = jax.tree.leaves(ts)[0].shape[0]
             pad = (-M) % n_dev
@@ -305,7 +311,7 @@ class KDPipeline:
             return sharded(ts, mask, bs)
 
         if weighted:
-            return jax.jit(lambda ts, bs, w: pre(ts, bs, w))
+            return named_program(PRECOMPUTE, lambda ts, bs, w: pre(ts, bs, w))
         return pre
 
     def precompute_teacher_probs(self, teacher_stack: PyTree,
@@ -527,7 +533,7 @@ class KDPipeline:
                 return st, losses
 
             fn = jax.vmap(run, in_axes=(0, None, None)) if multi else run
-            self._scan_fns[multi] = jax.jit(fn)
+            self._scan_fns[multi] = named_program(KD_SCAN, fn)
         return self._scan_fns[multi]
 
     # ------------------------------------------------ stepped escape hatch
@@ -543,7 +549,7 @@ class KDPipeline:
 
             fn = jax.vmap(one, in_axes=(0, 0, None, None, None)) \
                 if multi else one
-            self._step_fns[multi] = jax.jit(fn)
+            self._step_fns[multi] = named_program(KD_SCAN, fn)
         return self._step_fns[multi]
 
     def _run_stepped(self, student, batches, probs, multi: bool):
@@ -582,11 +588,13 @@ class KDPipeline:
         teacher cache instead of the uniform Eq. 3 mean.
         """
         batches = self.batches_for(server_batches)
-        cache = self.precompute_cache(teacher_stack, batches,
-                                      weights=teacher_weights)
-        if self.scan_capable():
-            return self._scan_fn(multi)(student, batches, cache)
-        return self._run_stepped(student, batches, cache, multi)
+        with span("fedsdd.kd.precompute"):
+            cache = self.precompute_cache(teacher_stack, batches,
+                                          weights=teacher_weights)
+        with span("fedsdd.kd.scan"):
+            if self.scan_capable():
+                return self._scan_fn(multi)(student, batches, cache)
+            return self._run_stepped(student, batches, cache, multi)
 
     def losses_info(self, losses) -> dict:
         """The per-round kd record (ONE host sync) for async losses."""

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.spans import span
 from repro.fedckpt.checkpointer import spill_members
 from repro.utils.pytree import tree_bytes, tree_stack, tree_unstack
 
@@ -115,33 +116,34 @@ class TeacherBank:
         groups whose model is a carry-forward this round (emptied by
         faults) — recorded so the ensemble's provenance stays auditable.
         """
-        if degraded:
-            self._degraded[int(round_idx)] = tuple(
-                sorted(int(k) for k in degraded))
-        if isinstance(global_models, (list, tuple)):
-            if len(global_models) != self.K:
-                raise ValueError(
-                    f"expected {self.K} group models, got {len(global_models)}")
-            member_stack = tree_stack(list(global_models))
-        else:
-            member_stack = global_models
-            lead = jax.tree.leaves(member_stack)[0].shape[0]
-            if lead != self.K:
-                raise ValueError(
-                    f"stacked model axis {lead} != K={self.K}")
-        if self._bank is None:
-            self._bank = jax.tree.map(
-                lambda m: jnp.zeros((self.R,) + m.shape,
-                                    self._store_dtype(m)),
-                member_stack)
-        slot = self._cursor
-        evicted = self._slot_rounds[slot]
-        if evicted is not None and self.spill_dir:
-            spill_members(self.spill_dir, evicted, self.round_stack(slot))
-        self._bank = _ring_write_fn()(self._bank, member_stack,
-                                      jnp.int32(slot))
-        self._slot_rounds[slot] = round_idx
-        self._cursor = (slot + 1) % self.R
+        with span("fedsdd.bank_push"):
+            if degraded:
+                self._degraded[int(round_idx)] = tuple(
+                    sorted(int(k) for k in degraded))
+            if isinstance(global_models, (list, tuple)):
+                if len(global_models) != self.K:
+                    raise ValueError(f"expected {self.K} group models, "
+                                     f"got {len(global_models)}")
+                member_stack = tree_stack(list(global_models))
+            else:
+                member_stack = global_models
+                lead = jax.tree.leaves(member_stack)[0].shape[0]
+                if lead != self.K:
+                    raise ValueError(
+                        f"stacked model axis {lead} != K={self.K}")
+            if self._bank is None:
+                self._bank = jax.tree.map(
+                    lambda m: jnp.zeros((self.R,) + m.shape,
+                                        self._store_dtype(m)),
+                    member_stack)
+            slot = self._cursor
+            evicted = self._slot_rounds[slot]
+            if evicted is not None and self.spill_dir:
+                spill_members(self.spill_dir, evicted, self.round_stack(slot))
+            self._bank = _ring_write_fn()(self._bank, member_stack,
+                                          jnp.int32(slot))
+            self._slot_rounds[slot] = round_idx
+            self._cursor = (slot + 1) % self.R
 
     # ------------------------------------------------------------- read
     def round_stack(self, slot: int) -> PyTree:

@@ -461,7 +461,7 @@ def flash_kd_fwd(student_logits, teacher_mean_logits,
         pl.pallas_call, grid=grid,
         out_specs=[stat] * n_stats,
         out_shape=[jax.ShapeDtypeStruct((B, LANES), jnp.float32)] * n_stats,
-        interpret=interpret)
+        interpret=interpret, name="flash_kd_fwd")
     if teacher_lse is not None:
         lse_t = teacher_lse.astype(jnp.float32)
         outs = call(
@@ -513,7 +513,7 @@ def flash_kd_bwd(student_logits, teacher_mean_logits, lse_s, lse_t, g,
         in_specs=[tile, tile, col, col, SMEM_SPEC],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((B, V), student_logits.dtype),
-        interpret=interpret,
+        interpret=interpret, name="flash_kd_bwd",
     )(student_logits, teacher_mean_logits, _col(lse_s), _col(lse_t),
       smem_scalar(g))
 

@@ -40,7 +40,7 @@ def weighted_average(stacked: jnp.ndarray, weights: jnp.ndarray,
                   pl.BlockSpec((N, db), lambda d: (0, d))],
         out_specs=pl.BlockSpec((db,), lambda d: (d,)),
         out_shape=jax.ShapeDtypeStruct((D,), stacked.dtype),
-        interpret=interpret,
+        interpret=interpret, name="weight_avg",
     )(w[:, None], stacked)
 
 
@@ -78,6 +78,6 @@ def multi_weighted_average(stacked: jnp.ndarray, weights: jnp.ndarray,
                   pl.BlockSpec((1, N, db), lambda g, d: (g, 0, d))],
         out_specs=pl.BlockSpec((1, 1, db), lambda g, d: (g, 0, d)),
         out_shape=jax.ShapeDtypeStruct((G, 1, D), stacked.dtype),
-        interpret=interpret,
+        interpret=interpret, name="weight_avg_multi",
     )(w[:, :, None], stacked)
     return out.reshape(G, D)

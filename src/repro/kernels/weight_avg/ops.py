@@ -15,7 +15,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.spans import named_program
 from repro.kernels.weight_avg import kernel, ref
+
+# the stable name of the Eq. 2 program in a profiler trace
+EQ2 = "fedsdd_eq2"
 
 
 def _use_pallas() -> bool:
@@ -48,7 +52,7 @@ def weighted_average(stacked, weights, block_d: int | None = None):
                              block_d)
 
 
-@partial(jax.jit, static_argnums=(2, 3))
+@partial(named_program, EQ2, static_argnums=(2, 3))
 def _weighted_average_tree(stacked_tree, weights, pallas, interpret):
     def leaf(x):
         N = x.shape[0]
@@ -85,7 +89,7 @@ def group_weighted_average(stacked, weights, block_d: int | None = None):
                                    _interpret(), block_d)
 
 
-@partial(jax.jit, static_argnums=(2, 3))
+@partial(named_program, EQ2, static_argnums=(2, 3))
 def _group_weighted_average_tree(stacked_tree, weights, pallas, interpret):
     def leaf(x):
         G, N = x.shape[:2]

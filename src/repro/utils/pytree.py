@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.spans import named_program
+
 PyTree = Any
 
 
@@ -99,7 +101,8 @@ def tree_where(pred, on_true: PyTree, on_false: PyTree) -> PyTree:
     return jax.tree.map(lambda a, b: jnp.where(pred, a, b), on_true, on_false)
 
 
-@functools.partial(jax.jit, static_argnames=("num_groups",))
+@functools.partial(named_program, "fedsdd_eq2",  # weight_avg/ops.py's EQ2
+                   static_argnames=("num_groups",))
 def _group_weighted_mean(stacked, w, gid, *, num_groups):
     # jitted: eager scatter_add dispatch is ~100x slower on CPU
     totals = jax.ops.segment_sum(w, gid, num_segments=num_groups)

@@ -239,12 +239,13 @@ def test_sharded_precompute_matches_vmap(monkeypatch):
 
 def test_overlap_records_round_walltime(task):
     """The executor's phase clock feeds bench_roundtime/scheduler: off
-    rounds carry the t_local/t_kd split, every round carries t_round."""
+    rounds carry the t_local/t_kd split, every round carries its
+    ``fedsdd.round`` span."""
     t = dataclasses.replace(task, eval_fn=None)
     st = run_overlap(t, "fedsdd", "off", rounds=1, K=2)
     rec = st.history[-1]
-    assert rec["t_round"] >= rec["t_local"] > 0
+    assert rec["spans"]["fedsdd.round"] >= rec["t_local"] > 0
     assert rec["t_kd"] > 0
     st = run_overlap(t, "fedsdd", "async", rounds=2, K=2)
-    assert all(r["t_round"] > 0 for r in st.history)
+    assert all(r["spans"]["fedsdd.round"] > 0 for r in st.history)
     assert "t_kd" not in st.history[-1]   # overlapped rounds don't sync
