@@ -15,8 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.utils.pytree import (
-    tree_group_weighted_mean, tree_stacked_weighted_mean, tree_weighted_mean,
-    tree_zeros_like,
+    group_weighted_mean, tree_group_weighted_mean, tree_stacked_weighted_mean,
+    tree_weighted_mean, tree_zeros_like,
 )
 
 PyTree = Any
@@ -46,20 +46,41 @@ def fedavg_aggregate_grouped(stacked: PyTree, num_samples, group_ids,
     ragged groups (C % K != 0) fall back to a fused segment reduction.
     Either way there is no per-group Python loop.
     """
+    w, gid = eq2_operands(num_samples, group_ids, num_groups)
+    return eq2_grouped(stacked, w, gid, num_groups)
+
+
+def eq2_operands(num_samples, group_ids, num_groups: int) -> tuple:
+    """Eq. 2's device operands, with its route chosen on the host.
+
+    Uniform group-major groups on the Pallas backend: ``((K, n) float32
+    weights, None)``, the batched multi-model kernel's route.  Otherwise
+    ``((C,) float32 weights, (C,) int32 group ids)``, the segment
+    reduction's.  ``eq2_grouped`` takes either pair."""
     from repro.kernels.weight_avg import ops as wops
     gid = np.asarray(group_ids)            # lint-ok: RA101 host group map
     counts = np.bincount(gid, minlength=num_groups)
     uniform = (counts == counts[0]).all() and counts[0] > 0
     group_major = bool((np.diff(gid) >= 0).all())
     if uniform and group_major and wops._use_pallas():
-        n = int(counts[0])
         w = jnp.asarray(
             np.asarray(num_samples, np.float64)  # lint-ok: RA101 host counts
-            .reshape(num_groups, n), jnp.float32)
+            .reshape(num_groups, int(counts[0])), jnp.float32)
+        return w, None
+    sizes = np.asarray(num_samples)        # lint-ok: RA101 host counts
+    return jnp.asarray(sizes, jnp.float32), jnp.asarray(gid, jnp.int32)
+
+
+def eq2_grouped(stacked: PyTree, w, gid, num_groups: int) -> PyTree:
+    """(K, ...) group averages of the (C, ...) client stack, over the
+    operands ``eq2_operands`` chose; runs eagerly or inside a program."""
+    if gid is None:
+        from repro.kernels.weight_avg import ops as wops
+        n = w.shape[1]
         regrouped = jax.tree.map(
             lambda x: x.reshape((num_groups, n) + x.shape[1:]), stacked)
         return wops.group_weighted_average_pytree(regrouped, w)
-    return tree_group_weighted_mean(stacked, num_samples, gid, num_groups)
+    return group_weighted_mean(stacked, w, gid, num_groups=num_groups)
 
 
 def survivor_group_weights(num_samples, group_ids, num_groups: int,

@@ -234,8 +234,13 @@ class ClientStore:
         if hit is not None:
             return hit
         rows = [self.get_data(int(c), int(n_pad)) for c in cids]
-        return self._data.put(key, jax.tree.map(lambda *xs: jnp.stack(xs),
-                                                *rows))
+        stack = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
+        # a miss is synchronous (rows are built through the host): ending
+        # it with the stack written lets the device release the rows and
+        # uploads the miss evicted before the round's programs allocate
+        # their outputs on top of them
+        jax.block_until_ready(stack)
+        return self._data.put(key, stack)
 
     def sampled_view(self, cids) -> SampledView:
         """Pin this round's sampled clients resident and hand back a

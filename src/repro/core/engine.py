@@ -25,10 +25,15 @@ by batch size and each bucket is vectorized independently.
 Aggregation consumes the stacked representation directly: Eq. 2 per group
 is a segment reduction over the client axis (``tree_group_weighted_mean``
 on CPU, the batched multi-model ``weight_avg`` Pallas kernel on TPU) —
-no per-client Python iteration anywhere on the hot path.
+no per-client Python iteration anywhere on the hot path.  Each end of
+local training is one program, not an eager op per leaf: ``start_state``
+(``fedsdd_local_start``) builds a bucket's start params and optimiser
+state, ``finish_round`` (``fedsdd_eq2``) takes the buckets' trained params
+in round order, averages each group and slices out the K models.
 """
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 from typing import Any, Callable, Optional, Sequence
@@ -39,15 +44,19 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis.spans import count, named_program, span
+from repro.core.aggregation import eq2_grouped, eq2_operands
 from repro.core.grouping import group_major_order
+from repro.kernels.weight_avg.ops import EQ2
 from repro.optim.optimizers import Optimizer, apply_updates
 from repro.sharding.specs import CLIENT_AXIS
 from repro.utils.pytree import tree_stack, tree_unstack, tree_where
 
 PyTree = Any
 
-# the stable name of the bucket-scan program in a profiler trace
+# the stable names of the local-training programs in a profiler trace
 BUCKET_SCAN = "fedsdd_bucket_scan"
+LOCAL_START = "fedsdd_local_start"
+ROUND_ORDER = "fedsdd_round_order"
 
 
 # =====================================================================
@@ -271,6 +280,7 @@ class VectorizedClientEngine:
         self.step_mode = step_mode
         self._vec_fn = None
         self._step_fn = None
+        self._start_fn = None
 
     def _resolved_step_mode(self) -> str:
         """See ``resolve_step_mode``: the engine's vmapped loop bodies run
@@ -353,7 +363,10 @@ class VectorizedClientEngine:
     def jit_programs(self) -> dict:
         """Built jitted programs by label — ``analysis.TraceGuard`` watches
         these to attribute a steady-state compile to its owner."""
-        out = {}
+        out = {"engine/end": _round_end,
+               "engine/round_order": _clients_in_round_order}
+        if self._start_fn is not None:
+            out["engine/start"] = self._start_fn
         if self._vec_fn is not None:
             out["engine/scan"] = self._vec_fn
         if self._step_fn is not None:
@@ -435,12 +448,13 @@ class VectorizedClientEngine:
         return self.finish_bucket(self.run_prepared(args), C,
                                   _home(stacked_params))
 
-    def train_round(self, rplan: RoundPlan, init_params_for: Callable,
-                    init_opt_state_for: Callable, run_buckets=None):
-        """Train every bucket; return round-ordered client stacks.
+    def train_round(self, rplan: RoundPlan, start_for: Callable,
+                    run_buckets=None) -> list:
+        """Train every bucket of the round plan.
 
-        ``init_params_for(plan) -> (Cb,...) stacked start params``;
-        ``init_opt_state_for(plan, stacked_params) -> stacked opt state``.
+        ``start_for(plan) -> (w0, s0)``: the bucket's (Cb, ...) stacked
+        start params and optimiser state (``start_state``, plus what the
+        optimiser keeps per client).
 
         ``run_buckets``, when given, replaces the per-bucket dispatch: it
         receives the list of padded arg tuples (see ``prepare_bucket``)
@@ -448,18 +462,16 @@ class VectorizedClientEngine:
         executor passes a closure that runs every bucket's scan AND the
         pending KD scan as one jitted program.
 
-        Returns ``(stacked_params, group_ids, sizes, buckets)`` where
-        ``stacked_params`` leaves are (C, ...) in the round's group-major
-        client order and ``buckets`` is a list of
-        (plan, trained_params, final_opt_state, start_params) per
-        batch-size bucket (SCAFFOLD's control update needs the bucket
-        view, since opt-state trees are stacked per bucket).
+        Returns ``buckets``, a list of (plan, trained_params,
+        final_opt_state, start_params) per batch-size bucket, rows in the
+        bucket's sorted-cid order: ``finish_round`` (or ``reassemble``)
+        puts them in round order.  SCAFFOLD's control update needs the
+        bucket view, since opt-state trees are stacked per bucket.
         """
         prepared = []
         with span("fedsdd.local.prep"):
             for plan in rplan.plans:
-                w0 = init_params_for(plan)
-                s0 = init_opt_state_for(plan, w0)
+                w0, s0 = start_for(plan)
                 args, C = self.prepare_bucket(plan, w0, s0)
                 prepared.append((plan, w0, args, C))
         # rows x steps of the step masks: shard padding and padded steps
@@ -475,53 +487,83 @@ class VectorizedClientEngine:
             for (plan, w0, _, C), out in zip(prepared, outs):
                 p, s, _ = self.finish_bucket(out, C, _home(w0))
                 buckets.append((plan, p, s, w0))
-            # reassemble in round (group-major) order: bucket rows are in
-            # sorted-cid order (the data-cache key), NOT round order — the
-            # permutation is required even for a single bucket
-            order = np.concatenate([b[0].order for b in buckets])
-            inv = np.argsort(order)
-            perm = jnp.asarray(inv)
-            stacked = jax.tree.map(
-                lambda *xs: jnp.concatenate(xs)[perm] if len(xs) > 1
-                else xs[0][perm],
-                *[b[1] for b in buckets])
-            group_ids = np.concatenate([b[0].group_of for b in buckets])[inv]
-            sizes = np.concatenate([b[0].sizes for b in buckets])[inv]
-        return stacked, group_ids, sizes, buckets
+        return buckets
+
+    # ---- the start of local training, one program --------------------
+    def start_state(self, global_models: Sequence[PyTree], group_of):
+        """A bucket's (Cb, ...) start params, each row its group's global
+        model, and their fresh optimiser state: one program, whose shapes
+        depend on Cb and the model alone."""
+        if self._start_fn is None:
+            init = self.optimizer.init
+
+            def start(models, gid):
+                w0 = jax.tree.map(lambda x: x[gid], tree_stack(models))
+                return w0, jax.vmap(init)(w0)
+
+            self._start_fn = named_program(LOCAL_START, start)
+        return self._start_fn(list(global_models),
+                              jnp.asarray(group_of, jnp.int32))
+
+
+def _round_order(buckets):
+    """The inverse of the buckets' concatenated round positions, and the
+    group ids, sizes and client ids in round order.  Bucket rows are in
+    sorted-cid order (the data-cache key), NOT round order: the
+    permutation is required even for a single bucket."""
+    plans = [b[0] for b in buckets]
+    inv = np.argsort(np.concatenate([p.order for p in plans]))
+    return inv, *(np.concatenate([getattr(p, f) for p in plans])[inv]
+                  for f in ("group_of", "sizes", "cids"))
+
+
+def _in_round_order(trained: Sequence[PyTree], perm) -> PyTree:
+    return jax.tree.map(
+        lambda *xs: (jnp.concatenate(xs) if len(xs) > 1 else xs[0])[perm],
+        *trained)
+
+
+_clients_in_round_order = named_program(ROUND_ORDER, _in_round_order)
+
+
+@functools.partial(named_program, EQ2, static_argnames=("num_groups",))
+def _round_end(trained, perm, w, gid, *, num_groups):
+    # the round-ordered client stack is a temporary, not an output: the
+    # outputs are allocated when the program is enqueued, while the
+    # bucket scans still hold their memory
+    agg = eq2_grouped(_in_round_order(trained, perm), w, gid, num_groups)
+    return agg, [jax.tree.map(lambda x, k=k: x[k], agg)
+                 for k in range(num_groups)]
+
+
+def finish_round(buckets, num_groups: int):
+    """Average each group of the round (Eq. 2) over every bucket's
+    trained params in one program.
+
+    Returns ``(stacked_globals, new_globals, group_ids, sizes, cids)``:
+    the (K, ...) group averages, the same as K pytrees, and the round's
+    host arrays in group-major order.  Eq. 2's route (the batched kernel
+    or the segment reduction) is chosen on the host, as
+    ``fedavg_aggregate_grouped`` chooses it, and its operands are the
+    clients in round order, as ``reassemble`` gives them."""
+    inv, gids, sizes, cids = _round_order(buckets)
+    w, gid = eq2_operands(sizes, gids, num_groups)
+    agg, models = _round_end([b[1] for b in buckets], jnp.asarray(inv), w,
+                             gid, num_groups=num_groups)
+    return agg, models, gids, sizes, cids
+
+
+def reassemble(buckets):
+    """The (C, ...) client stack in round order, in one program, with its
+    group ids and sizes: for the rounds whose Eq. 2 is masked or robust,
+    and for ensembles of the clients themselves."""
+    inv, gids, sizes, _ = _round_order(buckets)
+    return (_clients_in_round_order([b[1] for b in buckets],
+                                    jnp.asarray(inv)), gids, sizes)
 
 
 def _home(tree):
     return jax.tree.leaves(tree)[0].sharding
-
-
-def aggregate_groups(stacked_params: PyTree, sizes, group_ids,
-                     num_groups: int, aggregator: str = "mean",
-                     trim_frac: float = 0.2,
-                     clip_norm=None, fallback_stacked=None) -> PyTree:
-    """Eq. 2 for every group at once over the client axis: the batched
-    multi-model weight_avg kernel on TPU, a fused segment reduction on
-    CPU — never a per-group Python loop.
-
-    ``aggregator``/``trim_frac``/``clip_norm`` route through the
-    Byzantine-robust statistics (core/robust_agg) instead; the "mean"
-    default keeps this the bit-identical Eq. 2 path.  ``clip_norm``
-    needs ``fallback_stacked`` (the (K, ...) round-start globals) as the
-    update reference point.
-    """
-    if aggregator != "mean" or clip_norm is not None:
-        from repro.core.robust_agg import robust_aggregate_grouped
-        agg, _degraded = robust_aggregate_grouped(
-            stacked_params, sizes, group_ids, num_groups,
-            aggregator=aggregator, trim_frac=trim_frac,
-            clip_norm=clip_norm, fallback_stacked=fallback_stacked)
-        return agg
-    from repro.core.aggregation import fedavg_aggregate_grouped
-    return fedavg_aggregate_grouped(stacked_params, sizes, group_ids,
-                                    num_groups)
-
-
-def stack_models(models: Sequence[PyTree]) -> PyTree:
-    return tree_stack(list(models))
 
 
 def unstack_models(stacked: PyTree) -> list[PyTree]:

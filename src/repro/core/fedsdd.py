@@ -974,8 +974,14 @@ class _VectorizedRoundOps:
         self.fault_info: dict = {}
         self.degraded: list = []
         self._surv = None
+        cfg = runner.cfg
+        # masked (faulted) and robust rounds keep their own Eq. 2 over the
+        # reassembled client stack; the others end in one program
+        self.eager_end = (self.faults is not None or cfg.aggregator != "mean"
+                          or cfg.clip_norm is not None)
+        self.stacked_clients = None
         self.results: list = []     # (stacked, gids, sizes, orders, cids)
-        self.buckets: list = []     # scaffold bookkeeping across subsets
+        self.buckets: list = []     # every subset's, in training order
 
     def fused_capable(self) -> bool:
         return self.eng._resolved_step_mode() == "scan"
@@ -1001,16 +1007,10 @@ class _VectorizedRoundOps:
                 rplan = vec_engine.plan_from_entries(
                     runner.task, ents, self.groups, store=store,
                     pad_to=self.pad_hints)
-                # (K, ...) per phase
-                stacked_k = tree_stack(state.global_models)
-            optimizer = self.eng.optimizer
 
-            def init_params_for(plan):
-                gid = jnp.asarray(plan.group_of)
-                return jax.tree.map(lambda x: x[gid], stacked_k)
-
-            def init_opt_state_for(plan, w0):
-                s0 = jax.vmap(optimizer.init)(w0)
+            def start_for(plan):
+                w0, s0 = self.eng.start_state(state.global_models,
+                                              plan.group_of)
                 if cfg.local_algo == "scaffold":
                     c_loc = tree_stack(view.controls(plan.cids))
                     nb = len(plan.cids)
@@ -1018,11 +1018,15 @@ class _VectorizedRoundOps:
                         lambda x: jnp.broadcast_to(x, (nb,) + x.shape),
                         state.scaffold_c_global)
                     s0 = s0._replace(c_local=c_loc, c_global=c_glob)
-                return s0
+                return w0, s0
 
-            stacked, gids, sizes, buckets = self.eng.train_round(
-                rplan, init_params_for, init_opt_state_for,
-                run_buckets=run_buckets)
+            buckets = self.eng.train_round(rplan, start_for,
+                                           run_buckets=run_buckets)
+        self.buckets.extend(buckets)
+        if not self.eager_end:
+            return      # aggregate() reorders and averages in one program
+        with span("fedsdd.local.reassemble"):
+            stacked, gids, sizes = vec_engine.reassemble(buckets)
         if self.faults is not None and self.faults.attacked:
             # Byzantine rows: same perturbation math as the sequential
             # engine's attack_model, scattered into this subset's stack
@@ -1080,8 +1084,18 @@ class _VectorizedRoundOps:
             state.scaffold_c_global = self.store.control_mean()
 
     def aggregate(self) -> list[PyTree]:
-        """Eq. 2 for every group at once — one fused segment reduction
-        over the round-ordered client stack."""
+        """Eq. 2 for every group at once over the round-ordered client
+        stack: one program (``finish_round``), or, for a masked or robust
+        round, the reassembled stack and the masked or robust
+        statistics."""
+        count("local_eager_ends", int(self.eager_end))
+        if not self.eager_end:
+            with span("fedsdd.eq2"):
+                (self.stacked_globals, new_globals, _, self.sizes,
+                 self.cids_round) = vec_engine.finish_round(
+                    self.buckets, self.runner.cfg.K)
+                self.new_globals = list(new_globals)
+            return self.new_globals
         if len(self.results) == 1:
             stacked, gids, sizes, _, cids = self.results[0]
         else:
@@ -1099,16 +1113,13 @@ class _VectorizedRoundOps:
             return self._eq2(stacked, gids, sizes, cids)
 
     def _eq2(self, stacked, gids, sizes, cids) -> list[PyTree]:
-        """Eq. 2 (plain, masked or robust) over the round-ordered stack,
-        unstacked into the K new global models."""
+        """Masked or robust Eq. 2 over the round-ordered stack, unstacked
+        into the K new global models."""
         self.stacked_clients, self.sizes = stacked, sizes
         self.cids_round = cids
         rf, cfg = self.faults, self.runner.cfg
         robust = cfg.aggregator != "mean" or cfg.clip_norm is not None
-        if rf is None and not robust:
-            self.stacked_globals = vec_engine.aggregate_groups(
-                stacked, sizes, gids, cfg.K)
-        elif not robust:
+        if not robust:
             surv = self._survivors()
             mask = np.asarray([int(c) in surv for c in cids])
             self.stacked_globals, self.degraded = \
@@ -1141,6 +1152,8 @@ class _VectorizedRoundOps:
 
     def _client_teacher_stack(self, new_globals) -> PyTree:
         cfg, runner = self.runner.cfg, self.runner
+        if self.stacked_clients is None:    # the fold made none
+            self.stacked_clients, _, _ = vec_engine.reassemble(self.buckets)
         teacher_stack, sizes = self.stacked_clients, list(self.sizes)
         if self.faults is not None:
             surv = self._survivors()

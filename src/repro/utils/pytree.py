@@ -103,7 +103,9 @@ def tree_where(pred, on_true: PyTree, on_false: PyTree) -> PyTree:
 
 @functools.partial(named_program, "fedsdd_eq2",  # weight_avg/ops.py's EQ2
                    static_argnames=("num_groups",))
-def _group_weighted_mean(stacked, w, gid, *, num_groups):
+def group_weighted_mean(stacked, w, gid, *, num_groups):
+    """``tree_group_weighted_mean`` over device operands: (C,) float32
+    weights and (C,) int32 group ids."""
     # jitted: eager scatter_add dispatch is ~100x slower on CPU
     totals = jax.ops.segment_sum(w, gid, num_segments=num_groups)
     norm = w / totals[gid]
@@ -126,7 +128,7 @@ def tree_group_weighted_mean(stacked: PyTree, weights, group_ids,
     """
     w = jnp.asarray(np.asarray(weights), dtype=jnp.float32)
     gid = jnp.asarray(np.asarray(group_ids), dtype=jnp.int32)
-    return _group_weighted_mean(stacked, w, gid, num_groups=num_groups)
+    return group_weighted_mean(stacked, w, gid, num_groups=num_groups)
 
 
 def tree_dot(a: PyTree, b: PyTree):
