@@ -1,5 +1,5 @@
-"""The benchmark's traffic: synthetic CIFAR-shaped images and their split
-over clients.
+"""The image family's data (``families/resnet.py``): synthetic
+CIFAR-shaped images and their split over clients.
 
 A copy, kept with the benchmark so that a change to the program's own
 data module cannot move the yardstick, of the synthetic stand-in

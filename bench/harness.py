@@ -2,11 +2,34 @@
 FedSDD rounds, time a window, check the first rounds against the
 reference, and reduce the trace.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric sits in a file of its own, found by name:
+Everything that belongs to one configuration, traffic mix, model family
+or per-layer metric sits in a file of its own, found by name:
 
 - ``configs/<config>.json``: the model and data set, with its source, the
-  keys it changes from the source (``reduced``) and what it assumes;
+  keys it changes from the source (``reduced``), what it assumes, and its
+  ``family``;
+- ``families/<family>.py``: what the configuration's kind of model
+  brings, as module-level names:
+
+  - ``federation(cfg, mix, data_seed)``: ``(client_data, server_inputs)``,
+    each client's shard an ``(inputs, targets)`` pair of numpy arrays
+    whose leading axis is the example axis;
+  - ``program(cfg, server_inputs, server_batch)``: the program's task
+    functions, built from ``src/``: ``loss_fn``, ``logits_fn`` (rows x
+    V), ``make_batch``, ``server_batches``, and ``features_fn`` /
+    ``head_fn`` where the model has that split (else None);
+  - ``make_init(cfg, weight_seed)``: ``init(key) -> params``, the
+    benchmark's own initial weights;
+  - ``plain_model(cfg)``: the benchmark's own model in ``jax.numpy``,
+    importing nothing of the program: ``(logits(params, inputs) ->
+    (rows, V), loss(params, inputs, targets, rows))``, the second the
+    local training loss over the minibatch ``rows`` of the client data;
+  - ``round_flops(cfg, job, runs, teachers)``: the model FLOPs of a round;
+  - ``REFERENCE_BLOCK``: ``(clients, teachers)`` that the round reference
+    runs at once, None for all;
+
+  and, for the benchmark's tests (``tests/conftest.py``), ``shrink(cell)``:
+  the cell cut to a size the CPU runs in seconds;
 - ``traffic/<mix>.json``: the federation (population, job, warm-up) and
   the program options the mix pins;
 - ``metrics/<metric>.py``: a reader ``read(ctx) -> float | None`` for
@@ -14,7 +37,7 @@ metric sits in a file of its own, found by name:
 - ``limits/<workload>.json``: the limits of the cell's compared numbers.
 
 The program is imported from ``src/`` beside this directory: the
-system under test, its FedSDD runner, ResNet and kernels.  Data,
+system under test, its FedSDD runner, models and kernels.  Data,
 initial weights, FLOP counts, peaks, the trace reduction and the
 reference are the benchmark's own.
 """
@@ -36,6 +59,10 @@ import numpy as np
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+FAMILIES = os.path.join(BENCH, "families")
+# what the harness and the round reference take from a family
+FAMILY_NAMES = ("federation", "program", "make_init", "plain_model",
+                "round_flops", "REFERENCE_BLOCK")
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
@@ -69,10 +96,34 @@ def load_cell(workload: str) -> dict:
                  if workload in m.get("workloads", [workload])]
     end_to_end = [m for m in bench["end_to_end"]
                   if workload in m.get("workloads", [workload])]
-    return {"name": workload, "chips": cell["chips"],
-            "config": _json("configs", f"{cell['config']}.json"),
+    config = _json("configs", f"{cell['config']}.json")
+    family_of(config)
+    from check import load_limits
+    return {"name": workload, "chips": cell["chips"], "config": config,
             "mix": _json("traffic", f"{cell['traffic']}.json"),
-            "end_to_end": end_to_end, "per_layer": per_layer}
+            "end_to_end": end_to_end, "per_layer": per_layer,
+            "limits": load_limits(BENCH, workload)}
+
+
+def family_of(cfg: dict):
+    """The configuration's model family: ``families/<family>.py``, loaded
+    by path once per process."""
+    name = cfg.get("family")
+    path = os.path.join(FAMILIES, f"{name}.py")
+    if not name or not os.path.isfile(path):
+        raise SystemExit(f"configuration {cfg.get('name')!r} names the "
+                         f"family {name!r}, which has no module at {path}")
+    key = "bench_family_" + name.replace(".", "_").replace("-", "_")
+    mod = sys.modules.get(key)
+    if mod is None or mod.__file__ != path:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        spec.loader.exec_module(mod)
+    missing = [n for n in FAMILY_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"the family module {path} has no {missing}")
+    return mod
 
 
 def run_seeds(seed: int) -> tuple[int, int]:
@@ -185,36 +236,19 @@ class Built:
 
 def build(cell: dict, seed: int, log=print) -> Built:
     """The cell's task and runner through the program's own entry points
-    (``FedTask`` + ``make_runner``)."""
-    import jax.numpy as jnp
-    from repro.configs.resnet_cifar import get_resnet_config
+    (``FedTask`` + ``make_runner``), with the family's data, task
+    functions and initial weights."""
     from repro.core.fedsdd import FedConfig, FedTask, make_runner
-    from repro.models.resnet import resnet_logits, resnet_loss
-
-    from data import make_federation
-    from reference import make_init
 
     cfg, mix = cell["config"], cell["mix"]
+    family = family_of(cfg)
     data_seed, weight_seed = run_seeds(seed)
-    client_data, server_x = make_federation(cfg, mix, data_seed)
-    rcfg = dataclasses.replace(
-        get_resnet_config(cfg["model"], cfg["num_classes"]),
-        depth=cfg["depth"])
-    B = mix["job"]["server_batch"]
-    server_batches = [{"x": jnp.asarray(server_x[i:i + B])}
-                      for i in range(0, len(server_x) - B + 1, B)]
-
-    def make_batch(ds, idx):
-        x, y = ds
-        return {"x": jnp.asarray(x[idx]), "y": jnp.asarray(y[idx])}
-
+    client_data, server_x = family.federation(cfg, mix, data_seed)
     clients = ProbedClients(client_data)
     task = FedTask(
-        init_fn=make_init(cfg["depth"], cfg["num_classes"], weight_seed),
-        loss_fn=lambda p, b: resnet_loss(p, b, rcfg),
-        logits_fn=lambda p, b: resnet_logits(p, b["x"], rcfg),
-        client_data=clients, server_batches=server_batches,
-        make_batch=make_batch, eval_fn=None)
+        init_fn=family.make_init(cfg, weight_seed), client_data=clients,
+        eval_fn=None,
+        **family.program(cfg, server_x, mix["job"]["server_batch"]))
     job = job_of(cell)
     known = {f.name for f in dataclasses.fields(FedConfig)}
     pins = {}
@@ -249,9 +283,7 @@ def initial_models(cell: dict, b: Built) -> list:
     in (the program's ``init_state`` passes the same keys to the task's
     ``init_fn``)."""
     import jax
-    from reference import make_init
-    init = make_init(cell["config"]["depth"], cell["config"]["num_classes"],
-                     b.weight_seed)
+    init = family_of(cell["config"]).make_init(cell["config"], b.weight_seed)
     keys = jax.random.split(jax.random.PRNGKey(b.schedule_seed), b.job["K"])
     return host_models([init(k) for k in keys])
 
@@ -325,8 +357,11 @@ def reference_rounds(cell: dict, b: Built, start: list, rounds: int,
                      dtype=None) -> list[dict]:
     import jax.numpy as jnp
     from reference import Reference
-    ref = Reference(cell["config"]["depth"], b.job,
-                    dtype=jnp.float32 if dtype is None else dtype)
+    family = family_of(cell["config"])
+    clients, teachers = family.REFERENCE_BLOCK
+    ref = Reference(family.plain_model(cell["config"]), b.job,
+                    dtype=jnp.float32 if dtype is None else dtype,
+                    clients=clients, teachers=teachers)
     return ref.run(start, b.client_data, b.server_x, b.sizes,
                    b.schedule_seed, rounds)
 
@@ -351,20 +386,6 @@ class Context:
     setup_programs: int              # programs built or loaded in set-up
     round_flops: list[int]           # model FLOPs of each window round
     peaks: dict
-    kd_shape: tuple                  # (B, V, teacher-cache itemsize)
-    eq2_shape: tuple                 # (G, N, leaf sizes)
-
-
-def model_flops(cfg: dict, job: dict, runs, teachers: int) -> int:
-    """Model FLOPs a round requires: each sampled client's local steps,
-    the teacher forwards over the server set, and the KD steps."""
-    from flops.resnet import forward_flops, train_step_flops
-    d, V = cfg["depth"], cfg["num_classes"]
-    local = sum(len(r.rows) * train_step_flops(d, V, r.rows.shape[1])
-                for r in runs)
-    pre = teachers * forward_flops(d, V, cfg["num_server"])
-    kd = job["distill_steps"] * train_step_flops(d, V, job["server_batch"])
-    return local + pre + kd
 
 
 @dataclasses.dataclass
@@ -404,7 +425,7 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
     devs = require_chips(cell["chips"]) if require_chip else jax.devices()
     dev = devs[0]
 
-    from check import judge, load_limits, readings
+    from check import judge, readings
     import device_trace as trace_lib
 
     warm = cell["mix"]["warmup_rounds"]
@@ -467,12 +488,9 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
     stats = dev.memory_stats() or {}
     peak = int(stats.get("peak_bytes_in_use", 0))
     job, cfg = b.job, cell["config"]
-    K, R = job["K"], job["R"]
-    round_flops = [model_flops(cfg, job, b.schedule(warm + 1 + i), K * R)
-                   for i in range(attempted)]
-    leaf_sizes = [int(np.prod(x.shape))
-                  for x in jax.tree.leaves(start[0])]
-    m = max(1, int(round(job["num_clients"] * job["participation"])))
+    round_flops = [family_of(cfg).round_flops(
+        cfg, job, b.schedule(warm + 1 + i), job["K"] * job["R"])
+        for i in range(attempted)]
     prog_rounds.append({"models": host_models(first[0]),
                         "kd_loss_first": records[0].get("kd_loss_first"),
                         "kd_loss_last": records[0].get("kd_loss_last")})
@@ -497,9 +515,7 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
                       setup_programs=setup_programs,
                       round_flops=round_flops,
                       peaks=peaks_for(dev.device_kind) if dev.platform == "tpu"
-                      else {},
-                      kd_shape=(job["server_batch"], cfg["num_classes"], 2),
-                      eq2_shape=(K, m // K, leaf_sizes))
+                      else {})
         for metric in cell["per_layer"]:
             value = _metric_reader(metric["name"])(ctx)
             if value is not None:
@@ -520,7 +536,7 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
             result["metrics"][metric["name"]] = {
                 "value": e2e[metric["name"]], "unit": metric["unit"]}
 
-    lim = load_limits(BENCH, workload)
+    lim = cell["limits"]
     t_ref = time.perf_counter()
     ref_rounds = reference_rounds(cell, b, start, warm + 1)
     reference_s = time.perf_counter() - t_ref

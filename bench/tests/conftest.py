@@ -1,4 +1,3 @@
-import copy
 import os
 import sys
 
@@ -11,18 +10,13 @@ sys.path[:0] = [BENCH, os.path.join(ROOT, "src")]
 
 
 def shrink(cell: dict) -> dict:
-    """A cell cut to a size the CPU runs in seconds: ResNet-8, ten
-    clients of a few dozen images, three KD steps."""
-    cell = copy.deepcopy(cell)
-    cell["config"].update(depth=8, num_train=512, num_server=64,
-                          distill_steps=3)
-    pop = cell["mix"]["population"]
-    if pop["partition"] == "dirichlet":
-        pop.update(num_clients=10, alpha=1.0, min_shard=16)
-    else:
-        pop.update(num_clients=8)
-    cell["mix"]["job"].update(server_batch=32, client_batch=16)
-    return cell
+    """The cell cut to the size its family gives for the CPU."""
+    import harness
+    family = harness.family_of(cell["config"])
+    if not hasattr(family, "shrink"):
+        raise AttributeError(f"the family module {family.__file__} has no "
+                             "shrink(cell) for the benchmark's tests")
+    return family.shrink(cell)
 
 
 @pytest.fixture(scope="module")

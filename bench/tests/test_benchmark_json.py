@@ -64,7 +64,10 @@ def test_every_cell_finds_its_files(bench):
     for c in bench["configs"]:
         assert c["file"].startswith("bench/")
         with open(os.path.join(ROOT, c["file"])) as f:
-            assert json.load(f)["reduced"] == c["reduced"]
+            cfg = json.load(f)
+        assert cfg["reduced"] == c["reduced"]
+        family = harness.family_of(cfg)      # refuses a module it lacks
+        assert all(hasattr(family, n) for n in harness.FAMILY_NAMES)
 
 
 def test_peaks_refuse_an_unknown_device():

@@ -11,15 +11,17 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import harness
 from flops.resnet import forward_flops, train_step_flops
-from reference import init_params, logits
+
+FAMILY = harness.family_of({"name": "resnet", "family": "resnet"})
 
 
 @pytest.mark.parametrize("depth,classes", [(20, 10), (56, 100)])
 def test_forward_flops_match_xla(depth, classes):
-    params = init_params(jax.random.PRNGKey(0), depth, classes)
+    params = FAMILY.init_params(jax.random.PRNGKey(0), depth, classes)
     x = jnp.zeros((2, 32, 32, 3), jnp.float32)
-    cost = jax.jit(lambda p, x: logits(p, x, depth, None)).lower(
+    cost = jax.jit(lambda p, x: FAMILY.logits(p, x, depth, None)).lower(
         params, x).compile().cost_analysis()
     ours = forward_flops(depth, classes, 2)
     assert 0.93 <= ours / cost["flops"] <= 1.0
