@@ -10,7 +10,8 @@ run).
 Every seed, in one process, is a whole run of the cell (``harness.session``:
 set-up, a window of ``--seconds``, which runs at least the one timed round
 that is compared, and the float32 reference), and gives a sound reading.
-For a control seed also the reference in bfloat16 in the program's place.
+For a control seed also the reference in the configuration's
+``control_dtype`` in the program's place.
 For a fault seed a run of the program with half of every local minibatch
 left out (the loss is the mean over the other half).  A round that
 leaves its models unchanged reads 1 by the measure and needs no run; it
@@ -27,11 +28,14 @@ It refuses where a reading of the control or of a fault would pass every
 limit.
 """
 import argparse
+import dataclasses
 import json
 import math
 import os
 import sys
 import time
+
+import numpy as np
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [BENCH, os.path.join(os.path.dirname(BENCH), "src")]
@@ -51,9 +55,12 @@ def plant_half_batch(built):
     built.task.loss_fn = half_batch(built.task.loss_fn)
 
 
-def unchanged(start, rounds):
-    """Round records of a step that returns its models unchanged."""
-    return [dict(r, models=start) for r in rounds]
+def unchanged(norms):
+    """The norms of a step that returns its models unchanged."""
+    return dataclasses.replace(
+        norms, first=np.zeros_like(norms.first),
+        update=np.zeros_like(norms.update),
+        change=np.zeros_like(norms.change))
 
 
 def read_rows(workload: str, paths) -> list[dict]:
@@ -92,7 +99,8 @@ def write_limits(workload: str, paths, bench_dir: str = BENCH) -> dict:
             if kind == "sound":
                 continue
             low = min(r[name] for r in rs)
-            need = 3 if kind in ("control_bf16", "unchanged") else 10
+            need = 3 if kind.startswith("control_") or kind == "unchanged" \
+                else 10
             if low > lower and low >= need * lower:
                 uppers[kind] = low
         upper = min(uppers.values()) if uppers else None
@@ -139,9 +147,8 @@ def main(argv=None) -> int:
         return 0
 
     import gc
-    import jax.numpy as jnp
     import harness
-    from check import readings
+    from check import readings, reduce
 
     out = open(args.out, "a") if args.out else None
 
@@ -162,16 +169,15 @@ def main(argv=None) -> int:
         emit("sound", seed, s.values, t0, correct=res["correct"],
              metrics={k: m["value"] for k, m in res["metrics"].items()},
              attempted=res["attempted"], reference_s=s.reference_s)
-        emit("unchanged", seed,
-             readings(s.start, unchanged(s.start, s.prog_rounds),
-                      s.ref_rounds), t0)
+        emit("unchanged", seed, readings(unchanged(s.prog), s.ref), t0)
         if seed in args.control_seeds:
             t1 = time.perf_counter()
-            ctl = harness.reference_rounds(
-                harness.load_cell(args.workload), s.built, s.start,
-                len(s.ref_rounds), dtype=jnp.bfloat16)
-            emit("control_bf16", seed, readings(s.start, ctl, s.ref_rounds),
-                 t1)
+            cell = harness.load_cell(args.workload)
+            ctl = reduce(s.start, harness.reference_rounds(
+                cell, s.built, s.start, len(s.ref.kd_losses),
+                dtype="control_dtype"))
+            emit(f"control_{cell['config']['control_dtype']}", seed,
+                 readings(ctl, s.ref), t1)
         del s
         gc.collect()
     for seed in args.fault_seeds:

@@ -5,7 +5,10 @@ The program's rounds are the set-up's warm-up rounds and the window's
 first timed round, all through the window's own ``run_round`` on the
 window's runner and state; the timed round runs the window's programs at
 the window's shapes with the teacher bank full.  The reference replays
-the same rounds from the same initial weights and data.
+the same rounds from the same initial weights and data.  Each side is
+reduced to per-leaf norms as its rounds arrive (``Side``, ``Norms``), so
+that it holds at most three sets of K models, in the dtype it stores
+them in.
 
 Two sound computations of these rounds drift apart: local SGD and
 above all KD's 125 momentum steps amplify rounding.  On the chip, the
@@ -13,7 +16,7 @@ reference started one float32 ulp away reads up to 0.2 on the KD
 student's median leaf after one round, and up to 1.8 on the worst leaf
 after four; its KD losses move by a fifth.  So the numbers compared are
 ones that such drift leaves steady, and each fails one of the control
-(the reference in bfloat16) and the faults:
+(the reference in the configuration's ``control_dtype``) and the faults:
 
 - ``first_round_gap``: the first round's group models that KD does not
   touch (groups 1..K-1: local SGD and Eq. 2 alone), leaf by leaf, the
@@ -36,6 +39,7 @@ also gives, not compared, the worst leaf's gaps and the KD losses' gap.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
 import os
@@ -72,33 +76,72 @@ def _gaps(prog: np.ndarray, ref: np.ndarray) -> tuple:
             float(np.mean(prog < STUCK * ref)))
 
 
-def readings(start: list, prog_rounds: list[dict],
-             ref_rounds: list[dict]) -> dict:
-    """The compared numbers and the others.  ``start``: the K initial
-    models; each round entry holds ``models`` (K host pytrees after the
-    round) and the KD losses ``kd_loss_first`` / ``kd_loss_last``."""
-    if len(prog_rounds) != len(ref_rounds):
+@dataclasses.dataclass
+class Norms:
+    """One side's rounds, reduced to what ``readings`` compares: per-leaf
+    norms over the K models (``_leaf_norms``) of round 1's change of
+    groups 1..K-1 (``first``), of the last round's change (``update``)
+    and of the change since the start (``change``), and each round's KD
+    losses ``(first step, last step)``."""
+    first: np.ndarray
+    update: np.ndarray
+    change: np.ndarray
+    kd_losses: list
+
+
+class Side:
+    """One side's rounds as they arrive (``add``), reduced to ``Norms``
+    (``close``).  It holds at most three K-sets: the start, the round
+    before the last, and the last."""
+
+    def __init__(self, start: list):
+        self.start = start
+        self.before = self.last = None
+        self.first = None
+        self.kd_losses: list = []
+
+    def add(self, record: dict):
+        """A round: ``models`` (the K host pytrees after it) and its KD
+        losses ``kd_loss_first`` / ``kd_loss_last``."""
+        models = record["models"]
+        if self.first is None:
+            self.first = _leaf_norms(models[1:], self.start[1:])
+        self.before = self.start if self.last is None else self.last
+        self.last = models
+        self.kd_losses.append((record["kd_loss_first"],
+                               record["kd_loss_last"]))
+
+    def close(self) -> Norms:
+        """The norms; the rounds' models are let go."""
+        norms = Norms(self.first, _leaf_norms(self.last, self.before),
+                      _leaf_norms(self.last, self.start), self.kd_losses)
+        self.before = self.last = None
+        return norms
+
+
+def reduce(start: list, rounds) -> Norms:
+    """``Norms`` of an iterable of round records from ``start``."""
+    side = Side(start)
+    for record in rounds:
+        side.add(record)
+    return side.close()
+
+
+def readings(prog: Norms, ref: Norms) -> dict:
+    """The compared numbers and the others, from the program's and the
+    reference's norms over the same rounds."""
+    if len(prog.kd_losses) != len(ref.kd_losses):
         raise ValueError("the program and the reference ran different "
                          "numbers of rounds")
     kd = 0.0
-    for p, r in zip(prog_rounds, ref_rounds):
-        for key in ("kd_loss_first", "kd_loss_last"):
-            a, b = p[key], r[key]
+    for p, r in zip(prog.kd_losses, ref.kd_losses):
+        for a, b in zip(p, r):
             g = abs(a - b) / max(abs(b), 1e-30) if a is not None else math.inf
             kd = max(kd, g if math.isfinite(g) else math.inf)
 
-    def before(rounds):
-        return rounds[-2]["models"] if len(rounds) > 1 else start
-
-    upd, upd_worst, _ = _gaps(
-        _leaf_norms(prog_rounds[-1]["models"], before(prog_rounds)),
-        _leaf_norms(ref_rounds[-1]["models"], before(ref_rounds)))
-    chg, chg_worst, stuck = _gaps(
-        _leaf_norms(prog_rounds[-1]["models"], start),
-        _leaf_norms(ref_rounds[-1]["models"], start))
-    first, _, _ = _gaps(
-        _leaf_norms(prog_rounds[0]["models"][1:], start[1:]),
-        _leaf_norms(ref_rounds[0]["models"][1:], start[1:]))
+    upd, upd_worst, _ = _gaps(prog.update, ref.update)
+    chg, chg_worst, stuck = _gaps(prog.change, ref.change)
+    first, _, _ = _gaps(prog.first, ref.first)
     return {"first_round_gap": first, "stuck_share": stuck,
             "update_gap": upd, "change_gap": chg,
             "update_worst_leaf": upd_worst, "change_worst_leaf": chg_worst,

@@ -16,6 +16,10 @@ the harness wrote around the window and around each round.
   events' own times, moved onto the trace's clock), inside a round
   without a finer span (``unattributed``), or between rounds (the
   harness).
+
+Stretches that the harness leaves out of its own clock inside the window
+(the compared round's copy to the host) are cut out of the window too:
+they count neither as busy nor as idle, nor in the window's length.
 """
 from __future__ import annotations
 
@@ -98,10 +102,11 @@ class TraceSummary:
     idle_gaps: list[tuple[str, int]]         # (what the host did, ns)
     rounds: list[tuple[int, int]] = field(default_factory=list)
     op_labels: dict[str, str] = field(default_factory=dict)
+    cut_ns: int = 0                          # left out of the window
 
     @property
     def window_s(self) -> float:
-        return (self.window[1] - self.window[0]) / 1e9
+        return (self.window[1] - self.window[0] - self.cut_ns) / 1e9
 
     @property
     def busy_s(self) -> float:
@@ -125,11 +130,12 @@ def find_xplane(directory: str) -> str:
 
 
 def summarize(path: str, compiles_wall=(), wall_at_window: int | None = None,
-              top: int = 10) -> TraceSummary | None:
+              top: int = 10, cut_wall=()) -> TraceSummary | None:
     """Reduce one xplane file; None where it holds no device plane (a run
     on the CPU).  ``compiles_wall``: ``(start, end)`` of each compile in
-    ns of ``time.time_ns``; ``wall_at_window``: that clock as the window
-    span opened, which moves the compiles onto the trace's clock."""
+    ns of ``time.time_ns``; ``cut_wall``: the same of each stretch left
+    out of the window; ``wall_at_window``: that clock as the window span
+    opened, which moves both onto the trace's clock."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     spans: dict[str, list[tuple[int, int]]] = {}
@@ -159,39 +165,53 @@ def summarize(path: str, compiles_wall=(), wall_at_window: int | None = None,
         raise ValueError(f"the trace holds no {WINDOW_SPAN!r} span")
     lo, hi = spans[WINDOW_SPAN][0]
     rounds = sorted(spans.get(ROUND_SPAN, []))
-    compiles = []
+    compiles, cuts = [], []
     if wall_at_window is not None:
         shift = lo - wall_at_window
         compiles = [(s + shift, e + shift) for s, e in compiles_wall]
-    summary = reduce_ops(device_ops, (lo, hi), rounds, compiles, top)
+        cuts = [(s + shift, e + shift) for s, e in cut_wall]
+    summary = reduce_ops(device_ops, (lo, hi), rounds, compiles, top, cuts)
     summary.op_labels = labels
     return summary
 
 
 def summarize_dir(directory: str, compiles_wall=(),
-                  wall_at_window: int | None = None) -> TraceSummary | None:
-    return summarize(find_xplane(directory), compiles_wall, wall_at_window)
+                  wall_at_window: int | None = None,
+                  cut_wall=()) -> TraceSummary | None:
+    return summarize(find_xplane(directory), compiles_wall, wall_at_window,
+                     cut_wall=cut_wall)
 
 
-def reduce_ops(device_ops, window, rounds, compiles, top: int = 10):
-    """The summary from per-device ``(name, start, end)`` operation lists."""
+def reduce_ops(device_ops, window, rounds, compiles, top: int = 10,
+               cuts=()):
+    """The summary from per-device ``(name, start, end)`` operation lists;
+    ``cuts``, stretches of the window to leave out of it."""
     lo, hi = window
+    pieces = gaps(union(clip(cuts, lo, hi)), lo, hi)
     busy_ns, op_ns, op_count = [], {}, {}
     all_gaps = []
     for ops in device_ops:
         inside = [(n, s, e) for n, s, e in ops if e > lo and s < hi]
-        merged = union(clip([(s, e) for _, s, e in inside], lo, hi))
-        busy_ns.append(sum(e - s for s, e in merged))
+        busy = 0
+        for plo, phi in pieces:
+            merged = union(clip([(s, e) for _, s, e in inside], plo, phi))
+            busy += covered(merged)
+            all_gaps += gaps(merged, plo, phi)
+        busy_ns.append(busy)
         for n, s, e in inside:
-            op_ns[n] = op_ns.get(n, 0) + (min(e, hi) - max(s, lo))
+            if any(cs <= s and e <= ce for cs, ce in cuts):
+                continue
+            t = min(e, hi) - max(s, lo) - covered(clip(cuts, max(s, lo),
+                                                       min(e, hi)))
+            op_ns[n] = op_ns.get(n, 0) + t
             op_count[n] = op_count.get(n, 0) + 1
-        all_gaps += gaps(merged, lo, hi)
     all_gaps.sort(key=lambda g: g[0] - g[1])
     idle = [(attribute(g, compiles, rounds), g[1] - g[0])
             for g in all_gaps[:top]]
     return TraceSummary(window=(lo, hi), devices=len(device_ops),
                         busy_ns=busy_ns, op_ns=op_ns, op_count=op_count,
-                        idle_gaps=idle, rounds=list(rounds))
+                        idle_gaps=idle, rounds=list(rounds),
+                        cut_ns=(hi - lo) - covered(pieces))
 
 
 def top_ops(summary: TraceSummary, top: int = 10) -> list[list]:

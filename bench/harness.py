@@ -43,7 +43,6 @@ reference are the benchmark's own.
 """
 from __future__ import annotations
 
-import copy
 import dataclasses
 import gc
 import importlib.util
@@ -265,9 +264,9 @@ def build(cell: dict, seed: int, log=print) -> Built:
 
 
 def host_models(models) -> list:
+    """The K models on the host, in the dtype the program stores them in."""
     import jax
-    return [jax.tree.map(lambda a: np.asarray(a, np.float32), m)
-            for m in jax.device_get(list(models))]
+    return jax.device_get(list(models))
 
 
 def round_record(state) -> dict:
@@ -290,13 +289,15 @@ def initial_models(cell: dict, b: Built) -> list:
 
 def warm_up(cell: dict, b: Built, rounds: int, compiles=None, log=None):
     """The set-up's first rounds, through the window's own call on the
-    window's runner and state: ``(state, start models, round records,
-    whether the clients the program asked for are the schedule's, the
-    shortest round's seconds less the compiles inside it)``."""
+    window's runner and state: ``(state, the program's side of the
+    comparison with these rounds added, whether the clients the program
+    asked for are the schedule's, the shortest round's seconds less the
+    compiles inside it)``."""
     import jax
+    from check import Side
     state = b.runner.init_state()
-    start = initial_models(cell, b)
-    records, agrees, steady = [], True, math.inf
+    side = Side(initial_models(cell, b))
+    agrees, steady = True, math.inf
     for t in range(1, rounds + 1):
         b.clients.asked.clear()
         n0 = len(compiles.spans) if compiles else 0
@@ -312,8 +313,8 @@ def warm_up(cell: dict, b: Built, rounds: int, compiles=None, log=None):
             log(f"warm-up round {t}: {time.perf_counter() - t0:.2f} s, "
                 f"{seconds:.2f} s less compiles")
         agrees &= b.clients.asked == [r.cid for r in b.schedule(t)]
-        records.append(round_record(state))
-    return state, start, records, agrees, steady
+        side.add(round_record(state))
+    return state, side, agrees, steady
 
 
 def window_horizon(seconds: float, steady_round_s: float) -> int:
@@ -327,9 +328,12 @@ def window_horizon(seconds: float, steady_round_s: float) -> int:
 def warm_window_shapes(b: Built, state, horizon: int, log=print) -> int:
     """Compile every local-training shape that the next ``horizon`` rounds
     use and the rounds run so far did not: each round that brings a new
-    shape runs once through ``run_round``, on a copy of the state (its own
-    teacher bank, an empty history) that is then dropped.  Returns the
-    rounds so run."""
+    shape runs once through ``run_round``, on a copy of the state (an
+    empty history) that is then dropped.  The copy shares the teacher
+    bank, whose ring is kept on the host meanwhile and put back after
+    the round where it was (each leaf's sharding, committed or not), so
+    that the device never holds a second ring.  Returns the rounds so
+    run."""
     import jax
     from schedule import bucket_shapes
     done = state.round
@@ -340,13 +344,22 @@ def warm_window_shapes(b: Built, state, horizon: int, log=print) -> int:
         if shape in seen:
             continue
         seen.add(shape)
-        scratch = dataclasses.replace(
-            state, round=t - 1, ensemble=copy.deepcopy(state.ensemble),
-            history=[])
+        bank = state.ensemble
+        ring, meta = bank.export_state()
+        leaves, tree = jax.tree.flatten(ring)
+        placed = [(a.sharding, a.committed) for a in leaves]
+        held = jax.device_get(leaves)
+        del ring, leaves
+        scratch = dataclasses.replace(state, round=t - 1, history=[])
         t0 = time.perf_counter()
         scratch = b.runner.run_round(scratch)
         jax.block_until_ready(scratch.global_models)
         del scratch
+        bank.import_state(None, meta)    # the scratch round's ring goes first
+        bank.import_state(jax.tree.unflatten(tree, [
+            jax.device_put(h, where) if committed else jax.device_put(h)
+            for h, (where, committed) in zip(held, placed)]), meta)
+        del held
         ran += 1
         log(f"warmed round {t}'s shape {shape} in "
             f"{time.perf_counter() - t0:.1f} s")
@@ -354,13 +367,16 @@ def warm_window_shapes(b: Built, state, horizon: int, log=print) -> int:
 
 
 def reference_rounds(cell: dict, b: Built, start: list, rounds: int,
-                     dtype=None) -> list[dict]:
-    import jax.numpy as jnp
+                     dtype: str = "reference_dtype"):
+    """The reference's rounds 1..``rounds`` from ``start``, each yielded
+    as it ends; ``dtype`` names the configuration's key of the dtype it
+    computes in (``reference_dtype``, or ``control_dtype`` for the
+    control)."""
     from reference import Reference
-    family = family_of(cell["config"])
+    cfg = cell["config"]
+    family = family_of(cfg)
     clients, teachers = family.REFERENCE_BLOCK
-    ref = Reference(family.plain_model(cell["config"]), b.job,
-                    dtype=jnp.float32 if dtype is None else dtype,
+    ref = Reference(family.plain_model(cfg), b.job, dtype=cfg[dtype],
                     clients=clients, teachers=teachers)
     return ref.run(start, b.client_data, b.server_x, b.sizes,
                    b.schedule_seed, rounds)
@@ -394,8 +410,8 @@ class Session:
     result: dict
     built: Built
     start: list            # the K initial models
-    prog_rounds: list      # the program's rounds 1..warm+1, as compared
-    ref_rounds: list       # the reference's, the same rounds
+    prog: object           # check.Norms of the program's rounds 1..warm+1
+    ref: object            # check.Norms of the reference's, the same rounds
     values: dict           # the compared numbers
     reference_s: float     # the reference's seconds
 
@@ -412,7 +428,13 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
     """One run of a cell.  The benchmark's tests pass a smaller ``cell``,
     skip the look for a chip, and ``plant(built)`` a fault in the program
     before its first round; ``calibrate.py``, which reads only the
-    compared numbers, skips warming the window's shapes."""
+    compared numbers, skips warming the window's shapes.
+
+    Each side of the comparison is reduced to leaf norms as its rounds
+    arrive (``check.Side``), so that the host holds at most three sets
+    of K models a side, in the dtype that side stores them in.  The
+    compared round's models come off the device as soon as that round
+    ends, and the copy is left out of the window's clock."""
     t_start = time.perf_counter() if t_start is None else t_start
     log = log or (lambda *a: print(*a, file=sys.stderr, flush=True))
     cell = cell or load_cell(workload)
@@ -425,7 +447,7 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
     devs = require_chips(cell["chips"]) if require_chip else jax.devices()
     dev = devs[0]
 
-    from check import judge, readings
+    from check import judge, readings, reduce
     import device_trace as trace_lib
 
     warm = cell["mix"]["warmup_rounds"]
@@ -434,8 +456,7 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
         log(f"built by {time.perf_counter() - t_start:.1f} s")
         if plant is not None:
             plant(b)
-        state, start, prog_rounds, agrees, steady = warm_up(
-            cell, b, warm, compiles, log)
+        state, side, agrees, steady = warm_up(cell, b, warm, compiles, log)
         horizon = window_horizon(seconds, steady)
         log(f"warm-up: {warm} rounds by {time.perf_counter() - t_start:.1f}"
             f" s, steady round {steady:.2f} s, horizon {horizon} rounds")
@@ -457,6 +478,9 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
         with jax.profiler.TraceAnnotation(trace_lib.WINDOW_SPAN):
             wall0 = time.time_ns()
             t0 = time.perf_counter()
+            # the compared round's copy to the host, left out of the clock
+            # and out of the traced window
+            copy_s, copy_wall = 0.0, []
             while True:
                 r0 = time.perf_counter()
                 with jax.profiler.TraceAnnotation(trace_lib.ROUND_SPAN):
@@ -466,11 +490,14 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
                 records.append(dict(state.history[-1]))
                 flags.append(finite(state.global_models))
                 if len(round_s) == 1:       # the round that is compared
-                    first = (list(state.global_models),
-                             list(b.clients.asked))
-                if time.perf_counter() - t0 >= limit:
+                    c0, w0 = time.perf_counter(), time.time_ns()
+                    side.add(round_record(state))
+                    asked = list(b.clients.asked)
+                    copy_s = time.perf_counter() - c0
+                    copy_wall = [(w0, time.time_ns())]
+                if time.perf_counter() - t0 - copy_s >= limit:
                     break
-            window_s = time.perf_counter() - t0
+            window_s = time.perf_counter() - t0 - copy_s
         if trace:
             t1 = time.perf_counter()
             jax.profiler.stop_trace()
@@ -491,11 +518,9 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
     round_flops = [family_of(cfg).round_flops(
         cfg, job, b.schedule(warm + 1 + i), job["K"] * job["R"])
         for i in range(attempted)]
-    prog_rounds.append({"models": host_models(first[0]),
-                        "kd_loss_first": records[0].get("kd_loss_first"),
-                        "kd_loss_last": records[0].get("kd_loss_last")})
-    agrees &= first[1] == [r.cid for r in b.schedule(warm + 1)]
-    del state, flags, first
+    agrees &= asked == [r.cid for r in b.schedule(warm + 1)]
+    start, prog = side.start, side.close()
+    del state, flags
     b.runner = None
     gc.collect()
 
@@ -505,7 +530,8 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
                          "count": len(devs), "memory_peak_bytes": peak}}
     if trace:
         t1 = time.perf_counter()
-        summary = trace_lib.summarize_dir(tmp, window_spans, wall0)
+        summary = trace_lib.summarize_dir(tmp, window_spans, wall0,
+                                          cut_wall=copy_wall)
         shutil.rmtree(tmp, ignore_errors=True)
         log(f"trace read in {time.perf_counter() - t1:.1f} s")
         if summary is None and dev.platform == "tpu":
@@ -538,15 +564,14 @@ def session(workload: str, seed: int, seconds: float, trace: bool,
 
     lim = cell["limits"]
     t_ref = time.perf_counter()
-    ref_rounds = reference_rounds(cell, b, start, warm + 1)
+    ref = reduce(start, reference_rounds(cell, b, start, warm + 1))
     reference_s = time.perf_counter() - t_ref
     log(f"reference: {warm + 1} rounds in {reference_s:.1f} s")
-    values = readings(start, prog_rounds, ref_rounds)
+    values = readings(prog, ref)
     ok, table = judge(values, lim["limits"] if lim else None)
     if not agrees:
         ok = False
         log("the clients the program trained are not the schedule's")
     result["correct"] = bool(ok and failed == 0)
     result["checks"] = table
-    return Session(result, b, start, prog_rounds, ref_rounds, values,
-                   reference_s)
+    return Session(result, b, start, prog, ref, values, reference_s)

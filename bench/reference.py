@@ -13,10 +13,38 @@ SGD with momentum 0.9 on the unlabeled server batches, cycled, against
 the mean teacher logit, with the loss tau^2 * KL(softmax(t/tau) ||
 softmax(s/tau)) (Eqs. 3-5).
 
-The configuration states float32 parameters, updates and accumulation;
-``dtype=float32`` computes so.  ``dtype=bfloat16`` is the control: the
-same arithmetic with parameters, floating inputs, activations and
-updates in bfloat16.
+The configuration states the reference's dtype (``reference_dtype``,
+float32 for parameters, updates and accumulation) and the control's
+(``control_dtype``: the same arithmetic with parameters, floating
+inputs, activations and updates in that dtype).
+
+The round streams through the host, so that a model the chip holds only
+a few times still fits.  The K models and the R*K teacher bank are host
+arrays; a model goes up to the device only while a program needs it:
+
+- Eq. 2, group by group: the group's model goes up once, its clients
+  train in blocks of ``clients``, and one float32 accumulator adds each
+  block's weighted sum; the new model comes back.  A family that fits
+  whole says None: the round's clients then train in one program from
+  the K models stacked, which returns the K groups' sums.
+- The mean teacher logit: ``teachers`` bank members go up at a time
+  (None: the whole bank), each block's share adding into one float32
+  logit accumulator.
+- KD: the student (group 0's new model) goes up, and comes back.
+
+Device budget, in copies of one model in float32, beyond the data (every
+client's inputs and targets, the server batches) and the logit buffers
+(the teacher logit, its accumulator and a block's share: one float32
+logit per server row each): between the programs at most max(3, T),
+T the teachers a block (``teachers``, R*K where None): the group model,
+the accumulator and a block's weighted sum (3), or a block of teachers,
+or the student before and after KD (2); with ``clients`` None, max(2K,
+T): the K models and their K sums.  Inside a program add its working
+set: a block of clients' training state (each client's parameters and
+gradient, about two copies a client, and the block's weighted sum), or
+the student's momentum and gradient (two).  The host holds the K models and the R*K
+bank, of which the newest K share K-1 models with the K: (R+1)*K copies
+in the reference's dtype at most.
 """
 from __future__ import annotations
 
@@ -58,17 +86,17 @@ class Reference:
     ``model`` is ``(logits, loss)``: ``logits(params, inputs) -> (rows, V)``
     and ``loss(params, inputs, targets, rows)``, the local training loss
     over the minibatch ``rows`` of ``(inputs, targets)``.  A round is
-    three programs: the sampled clients' SGD (each client's minibatches
+    three programs: a block of clients' SGD (each client's minibatches
     padded to the population's longest shard, the padded steps leaving
-    the model as it was) with Eq. 2's weighted means; the teacher
-    ensemble's mean logits over the server batches; and the KD steps.
+    the model as it was) with their share of Eq. 2's weighted means; a
+    block of teachers' share of the mean logit over the server batches;
+    and the KD steps.
 
-    ``clients`` and ``teachers`` say how many clients, and how many
-    teachers, run at once; None runs all of them in one program.  In
-    blocks, each block is one ``vmap``ped program, and the blocks add up
-    Eq. 2's weighted sum and the teachers' shares of the mean logit in
-    float32, so that a model too large for the whole round at once still
-    fits."""
+    ``clients`` and ``teachers`` say how many clients of one group, and
+    how many teachers, run at once; None runs the whole round's clients,
+    or the whole bank.  Each block is one ``vmap``ped program; every
+    client block of a round has one shape, a short one padded with
+    clients of no steps and no weight."""
 
     def __init__(self, model, job: dict, dtype=jnp.float32,
                  clients: int | None = None, teachers: int | None = None):
@@ -80,12 +108,13 @@ class Reference:
         tau = job["temperature"]
         dt = self.dtype
 
-        def trained(models, x_all, y_all, rows, live, weights):
-            """``models``: the K group models; ``rows`` (C, S, B) indices
-            into ``x_all``, ``live`` (C, S) which steps are real,
-            ``weights`` (K, C) each group's Eq. 2 weights of the clients."""
-            group = jnp.argmax(weights, axis=0)
-
+        def local_sum(models, x_all, y_all, group, rows, live, weights):
+            """The block's clients trained from their group's model, and
+            each group's weighted sum of them in float32: ``models`` the
+            G group models stacked, ``group`` (C,) each client's index
+            into them, ``rows`` (C, S, B) indices into ``x_all``, ``live``
+            (C, S) which steps are real, ``weights`` (G, C) each group's
+            Eq. 2 weights of the clients."""
             def client(k, rows_c, live_c):
                 p0 = jax.tree.map(lambda m: m[k], models)
 
@@ -98,17 +127,10 @@ class Reference:
 
                 return jax.lax.scan(step, p0, (rows_c, live_c))[0]
 
-            return jax.vmap(client)(group, rows, live)
-
-        def weighted(t, weights, out):
-            return jnp.sum(
+            trained = jax.vmap(client)(group, rows, live)
+            return jax.tree.map(lambda t: jnp.sum(
                 weights.reshape(weights.shape + (1,) * (t.ndim - 1))
-                * t.astype(jnp.float32)[None], axis=1).astype(out)
-
-        def local_sum(models, x_all, y_all, rows, live, weights):
-            return jax.tree.map(
-                lambda t: weighted(t, weights, jnp.float32),
-                trained(models, x_all, y_all, rows, live, weights))
+                * t.astype(jnp.float32)[None], axis=1), trained)
 
         def logits_of(members, server):
             return jax.vmap(lambda m: jax.vmap(lambda xb: fwd(m, xb))(server))(
@@ -138,42 +160,79 @@ class Reference:
         self._teacher_share = jax.jit(teacher_share, static_argnums=2)
         self._kd = jax.jit(kd, static_argnums=3)
 
-    def _eq2(self, models, x_all, y_all, rows, live, weights):
-        """Eq. 2's K models from the round's clients, in blocks of
-        ``self.clients``; a short last block is padded with clients of
-        no steps and no weight, so that every block has one shape."""
-        C, b = rows.shape[0], self.clients
-        pad = 0 if b is None or b >= C else -C % b
-        rows = np.concatenate([rows, np.zeros((pad,) + rows.shape[1:],
-                                              rows.dtype)])
-        live = np.concatenate([live, np.zeros((pad,) + live.shape[1:], bool)])
-        weights = np.concatenate([weights, np.zeros((len(weights), pad),
-                                                    weights.dtype)], axis=1)
-        acc = None
-        for part in _blocks(C + pad, b):
-            s = self._local_sum(models, x_all, y_all, jnp.asarray(rows[part]),
-                                jnp.asarray(live[part]),
-                                jnp.asarray(weights[:, part]))
-            acc = s if acc is None else jax.tree.map(jnp.add, acc, s)
-        return jax.tree.map(lambda a: a.astype(self.dtype), acc)
+    def _up(self, model):
+        """A host model on the device, in the reference's dtype."""
+        return jax.tree.map(lambda a: jnp.asarray(a, self.dtype), model)
+
+    def _eq2(self, models: list, x_all, y_all, group, rows, live,
+             weights) -> list:
+        """Eq. 2's K new models, on the host, from the round's clients
+        (``group`` (C,) each one's group, ``weights`` (C,) their shard
+        sizes, Eq. 2's weights before normalising): with ``self.clients``
+        None in one program over the K models; else group by group, each
+        group's clients in blocks of ``self.clients``, a short block
+        padded with clients of no steps and no weight."""
+        K = len(models)
+        if self.clients is None:
+            work = [(list(range(K)), list(range(len(group))))]
+            b = len(group)
+        else:
+            work = [([k], [c for c in range(len(group)) if group[c] == k])
+                    for k in range(K)]
+            b = self.clients
+        new = []
+        for ks, clients in work:
+            pad = -len(clients) % b
+            n = len(clients) + pad
+            w = np.zeros((len(ks), n), np.float32)
+            for i, c in enumerate(clients):
+                w[ks.index(group[c]), i] = weights[c]
+            w /= w.sum(axis=1, keepdims=True)
+            own = np.zeros(n, np.int32)
+            own[:len(clients)] = [ks.index(group[c]) for c in clients]
+            b_rows = np.concatenate([rows[clients], np.zeros(
+                (pad,) + rows.shape[1:], rows.dtype)])
+            b_live = np.concatenate([live[clients],
+                                     np.zeros((pad,) + live.shape[1:], bool)])
+            up = jax.tree.map(lambda *a: jnp.asarray(np.stack(a), self.dtype),
+                              *[models[k] for k in ks])
+            acc = None
+            for part in _blocks(n, b):
+                s = self._local_sum(up, x_all, y_all, jnp.asarray(own[part]),
+                                    jnp.asarray(b_rows[part]),
+                                    jnp.asarray(b_live[part]),
+                                    jnp.asarray(w[:, part]))
+                acc = s if acc is None else jax.tree.map(jnp.add, acc, s)
+                del s
+            del up
+            host = jax.device_get(jax.tree.map(
+                lambda a: a.astype(self.dtype), acc))
+            del acc
+            new += [jax.tree.map(lambda a, i=i: a[i], host)
+                    for i in range(len(ks))]
+        return new
 
     def _teacher_logits(self, bank: list, server):
-        """The mean teacher logit over the bank's K*R models, in blocks of
-        ``self.teachers``."""
-        members = jax.tree.map(lambda *a: jnp.concatenate(a), *bank)
-        n = len(jax.tree.leaves(members)[0])
-        acc = None
+        """The mean teacher logit over the bank (R rounds, newest first, of
+        K host models each), ``self.teachers`` members at a time."""
+        members = [m for models in bank for m in models]
+        n, acc = len(members), None
         for part in _blocks(n, self.teachers):
-            s = self._teacher_share(jax.tree.map(lambda a: a[part], members),
-                                    server, n)
+            block = jax.tree.map(lambda *a: jnp.asarray(np.stack(a),
+                                                        self.dtype),
+                                 *members[part])
+            s = self._teacher_share(block, server, n)
+            del block
             acc = s if acc is None else acc + s
+            del s
         return acc.astype(self.dtype)
 
     def run(self, models: list, client_data, server_x: np.ndarray,
-            sizes, seed: int, rounds: int) -> list[dict]:
-        """``rounds`` FedSDD rounds from the K initial ``models``; for each
-        round the K models after it (host float32) and the KD loss of its
-        first and last step."""
+            sizes, seed: int, rounds: int):
+        """``rounds`` FedSDD rounds from the K initial host ``models``:
+        yields, as each round ends, the K models after it (host arrays in
+        the reference's dtype) and the KD loss of its first and last
+        step."""
         job = self.job
         K, R, B = job["K"], job["R"], job["server_batch"]
         dt = self.dtype
@@ -184,10 +243,7 @@ class Reference:
         server = _inputs(server_x[:nb * B].reshape(
             (nb, B) + server_x.shape[1:]), dt)
         steps = job["local_epochs"] * (max(sizes) // job["client_batch"])
-        models = jax.tree.map(lambda *a: jnp.asarray(np.stack(a), dt),
-                              *models)
         bank: list = []
-        out = []
         for t in range(1, rounds + 1):
             runs = round_schedule(sizes, job["participation"], K,
                                   job["client_batch"], job["local_epochs"],
@@ -199,23 +255,20 @@ class Reference:
                                  "minibatch sizes")
             rows = np.zeros((C, steps, bs), np.int32)
             live = np.zeros((C, steps), bool)
-            weights = np.zeros((K, C), np.float32)
             for c, r in enumerate(runs):
                 rows[c, :len(r.rows)] = r.rows + offsets[r.cid]
                 live[c, :len(r.rows)] = True
-                weights[r.group, c] = r.n
-            weights /= weights.sum(axis=1, keepdims=True)
-            new = self._eq2(models, x_all, y_all, rows, live, weights)
+            group = [r.group for r in runs]
+            weights = [r.n for r in runs]
+            new = self._eq2(models, x_all, y_all, group, rows, live, weights)
             bank = [new] + bank[:R - 1]
             zt = self._teacher_logits(bank, server)
-            student, losses = self._kd(jax.tree.map(lambda a: a[0], new),
-                                       server, zt, job["distill_steps"])
-            models = jax.tree.map(lambda m, s: m.at[0].set(s), new, student)
-            host = jax.tree.map(lambda a: np.asarray(a, np.float32), models)
+            student, losses = self._kd(self._up(new[0]), server, zt,
+                                       job["distill_steps"])
+            del zt
+            models = [jax.device_get(student)] + new[1:]
+            del student
             losses = np.asarray(losses, np.float32)
-            out.append({
-                "models": [jax.tree.map(lambda a, k=k: a[k], host)
-                           for k in range(K)],
-                "kd_loss_first": float(losses[0]),
-                "kd_loss_last": float(losses[-1])})
-        return out
+            yield {"models": models,
+                   "kd_loss_first": float(losses[0]),
+                   "kd_loss_last": float(losses[-1])}

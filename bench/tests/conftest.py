@@ -19,6 +19,20 @@ def shrink(cell: dict) -> dict:
     return family.shrink(cell)
 
 
+def token_cell(pins=None) -> dict:
+    """The token family's cell at its CPU size."""
+    import cells
+    return cells.token_cell(pins, shrink)
+
+
+@pytest.fixture(scope="module")
+def families():
+    """The token family beside the benchmark's, for this module."""
+    import cells
+    with cells.token_family():
+        yield
+
+
 @pytest.fixture(scope="module")
 def own_cache(tmp_path_factory):
     """The program's compile cache in a directory of the tests' own."""

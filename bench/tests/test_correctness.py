@@ -1,5 +1,5 @@
-"""``correct`` fails where it must: the control (the reference in
-bfloat16 in the program's place) and the faults a one-chip training
+"""``correct`` fails where it must: the control (the reference in the
+configuration's ``control_dtype`` in the program's place) and the faults a one-chip training
 cell can have, a round that returns its models unchanged and a local
 step that leaves half of each minibatch out.  At a tiny size on the CPU:
 each reads at least ten times the sound run on one of the compared
@@ -7,12 +7,11 @@ numbers, and a run with a fault planted under the harness is never
 ``correct``."""
 import math
 
-import jax.numpy as jnp
 import pytest
 
 import harness
-from calibrate import plant_half_batch
-from check import NUMBERS, judge, load_limits, readings
+from calibrate import plant_half_batch, unchanged
+from check import NUMBERS, judge, load_limits, readings, reduce
 from conftest import shrink
 
 WORKLOAD = "resnet20-cifar10.cross_device"
@@ -42,7 +41,7 @@ def _separates(values, sound_values):
 def test_sound_run_compares_a_timed_round(sound, tiny):
     warm = tiny["mix"]["warmup_rounds"]
     assert sound.result["attempted"] >= 1
-    assert len(sound.prog_rounds) == len(sound.ref_rounds) == warm + 1
+    assert len(sound.prog.kd_losses) == len(sound.ref.kd_losses) == warm + 1
     assert all(math.isfinite(sound.values[k]) for k in NUMBERS)
 
 
@@ -58,9 +57,17 @@ def test_no_limit_is_never_correct(sound):
 
 
 def test_control_is_not_correct(tiny, sound):
-    ctl = harness.reference_rounds(tiny, sound.built, sound.start,
-                                   len(sound.ref_rounds), dtype=jnp.bfloat16)
-    values = readings(sound.start, ctl, sound.ref_rounds)
+    ctl = reduce(sound.start, harness.reference_rounds(
+        tiny, sound.built, sound.start, len(sound.ref.kd_losses),
+        dtype="control_dtype"))
+    values = readings(ctl, sound.ref)
+    assert _separates(values, sound.values), (values, sound.values)
+    assert not judge(values, _limits())[0]
+
+
+def test_unchanged_norms_are_not_correct(sound):
+    values = readings(unchanged(sound.prog), sound.ref)
+    assert values["first_round_gap"] == values["stuck_share"] == 1.0
     assert _separates(values, sound.values), (values, sound.values)
     assert not judge(values, _limits())[0]
 
@@ -88,9 +95,33 @@ def test_faults_are_not_correct(own_cache, tiny, sound, plant):
 
 
 def test_warming_window_shapes_leaves_the_run_alone(tiny, sound, monkeypatch):
+    """The same numbers as a run that warms nothing, and the teacher
+    bank's ring back where it was: each leaf's sharding, and committed to
+    its device where it was (as a ring placed across chips is)."""
+    import jax
     monkeypatch.setattr(harness, "window_horizon", lambda *a: 8)
+    warm = harness.warm_window_shapes
+    placed = []
+
+    def watched(b, state, horizon, log=print):
+        def where():
+            return [(a.sharding, a.committed) for a in
+                    jax.tree.leaves(state.ensemble.export_state()[0])]
+        ring, meta = state.ensemble.export_state()
+        state.ensemble.import_state(
+            jax.device_put(ring, jax.devices()[0]), meta)
+        del ring
+        before = where()
+        ran = warm(b, state, horizon, log)
+        placed.append((before, where()))
+        return ran
+
+    monkeypatch.setattr(harness, "warm_window_shapes", watched)
     said = []
     warmed = harness.session(WORKLOAD, 77, 0.0, False, require_chip=False,
                              cell=tiny, log=said.append)
     assert any(line.startswith("warmed round") for line in said), said
     assert warmed.values == sound.values
+    [(before, after)] = placed
+    assert before and all(committed for _, committed in before)
+    assert after == before

@@ -3,8 +3,6 @@ the lookup and its refusal, a family of another kind of model (the
 token-sequence family under ``tests/families/``) run end to end through
 the unchanged harness, and the round reference in blocks against the
 reference all at once."""
-import copy
-import json
 import os
 
 import jax
@@ -14,42 +12,12 @@ import pytest
 
 import harness
 from calibrate import plant_half_batch
-from check import NUMBERS, readings
-from conftest import HERE, shrink
+from check import NUMBERS, readings, reduce
+from cells import TOKENS
+from conftest import shrink, token_cell
 from reference import Reference
 
 WORKLOAD = "resnet20-cifar10.cross_device"
-TOKENS = "tokens-tiny.cross_device"
-TOKEN_FAMILY = os.path.join(HERE, "families", "tokens.py")
-DATA = os.path.join(HERE, "data")
-
-
-@pytest.fixture(scope="module")
-def families(tmp_path_factory):
-    """A families directory with the benchmark's families and the token
-    family, in the harness's place for this module."""
-    where = tmp_path_factory.mktemp("families")
-    for name in os.listdir(harness.FAMILIES):
-        os.symlink(os.path.join(harness.FAMILIES, name), where / name)
-    os.symlink(TOKEN_FAMILY, where / "tokens.py")
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(harness, "FAMILIES", str(where))
-        yield
-
-
-def token_cell(pins=None) -> dict:
-    """The token family's cell: its configuration under the ResNet
-    cell's traffic mix (with ``pins`` added), at its CPU size, with its
-    limits (``data/limits/``)."""
-    base = harness.load_cell(WORKLOAD)
-    with open(os.path.join(DATA, "tokens-tiny.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(DATA, "limits", f"{TOKENS}.json")) as f:
-        limits = json.load(f)
-    mix = copy.deepcopy(base["mix"])
-    mix["pins"].update(pins or {})
-    return shrink(dict(base, name=TOKENS, config=config, mix=mix,
-                       limits=limits))
 
 
 @pytest.mark.parametrize("cfg", [
@@ -137,8 +105,8 @@ def _reference(cell, b, start, clients, teachers):
     family = harness.family_of(cell["config"])
     ref = Reference(family.plain_model(cell["config"]), b.job,
                     clients=clients, teachers=teachers)
-    return ref.run(start, b.client_data, b.server_x, b.sizes,
-                   b.schedule_seed, cell["mix"]["warmup_rounds"] + 1)
+    return list(ref.run(start, b.client_data, b.server_x, b.sizes,
+                        b.schedule_seed, cell["mix"]["warmup_rounds"] + 1))
 
 
 # Blocks vmap the local training over fewer clients and add Eq. 2's
@@ -154,7 +122,7 @@ def _reference(cell, b, start, clients, teachers):
 def test_reference_in_blocks_agrees(all_at_once, family, clients, teachers):
     cell, b, start, whole = all_at_once[family]
     blocked = _reference(cell, b, start, clients, teachers)
-    values = readings(start, blocked, whole)
+    values = readings(reduce(start, blocked), reduce(start, whole))
     limits = cell["limits"]["limits"]
     for name in NUMBERS:
         assert values[name] <= limits[name] / 10, (name, values)
@@ -170,8 +138,7 @@ def test_teacher_blocks_sum_the_whole_bank(all_at_once, family):
     nb = len(b.server_x) // B
     server = jnp.asarray(b.server_x[:nb * B].reshape(
         (nb, B) + b.server_x.shape[1:]))
-    stack = jax.tree.map(lambda *a: jnp.stack(a), *start)
-    bank = [stack, jax.tree.map(lambda a: 0.5 * a, stack)]
+    bank = [start, [jax.tree.map(lambda a: 0.5 * a, m) for m in start]]
     whole = np.asarray(Reference(model, b.job)._teacher_logits(bank, server))
     for teachers in (1, 3):
         got = Reference(model, b.job, teachers=teachers)._teacher_logits(

@@ -65,3 +65,36 @@ def test_trace_file(tmp_path):
     assert {what for what, _ in s.idle_gaps[2:]} == {
         "round 1: host, unattributed", "round 2: host, unattributed"}
     assert dt.top_ops(s, 1) == [["while.7", pytest.approx(0.068)]]
+
+
+def test_a_cut_stretch_is_neither_busy_nor_idle():
+    ops = [[("a", 0, 40), ("b", 35, 45), ("b", 42, 48), ("a", 60, 100)]]
+    s = dt.reduce_ops(ops, (0, 100), [(0, 40), (60, 100)], [],
+                      cuts=[(40, 60)])
+    assert s.busy_ns == [80]
+    assert s.window_s == pytest.approx(80e-9)
+    assert s.idle_gaps == []
+    assert s.op_ns == {"a": 80, "b": 5}
+    assert s.op_count == {"a": 2, "b": 1}
+
+
+def test_the_copy_to_the_host_is_not_idle(tmp_path):
+    """The recorded trace with the stretch between its rounds, 40-50 ms,
+    taken by the compared round's copy to the host: it leaves the
+    window, and its idle time with it."""
+    from jax.profiler import ProfileData
+    with open(os.path.join(DATA, "two_rounds.xspace.txt")) as f:
+        text = "".join(line for line in f if not line.startswith("#"))
+    path = tmp_path / "plugins" / "profile" / "run" / "host.xplane.pb"
+    path.parent.mkdir(parents=True)
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+    ms = 1_000_000
+    wall = 5_000 * ms
+    s = dt.summarize_dir(str(tmp_path), [(wall + 41 * ms, wall + 49 * ms)],
+                         wall, cut_wall=[(wall + 40 * ms, wall + 50 * ms)])
+    assert s.window_s == pytest.approx(0.09)
+    assert s.busy_s == pytest.approx(0.077)
+    assert sorted(ns for _, ns in s.idle_gaps) == \
+        [1 * ms, 1 * ms, 1 * ms, 2 * ms, 2 * ms, 6 * ms]
+    assert "compile" not in {what for what, _ in s.idle_gaps}
+    assert s.idle_gaps[0] == ("harness, between rounds", 6 * ms)
